@@ -84,6 +84,9 @@ def main() -> None:
             f"unknown benchmark name(s): {', '.join(unknown)} "
             f"(available: {', '.join(benches)})")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     os.makedirs("artifacts/bench", exist_ok=True)
     failures = []
     mirrored_all: list[str] = []

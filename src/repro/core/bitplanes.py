@@ -22,6 +22,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.quantize import QuantizedTensor, container_dtype
 
@@ -115,7 +116,10 @@ PAPER_DEFAULT = PlaneSchedule(bits=16, widths=(2,) * 8)
 # ---------------------------------------------------------------------------
 # Dense bit-packing: planes are transmitted packed (w bits per element),
 # not one container-int per element — this is what keeps "no size
-# increase" true on the wire.
+# increase" true on the wire. Packing is host work in NumPy: wire bytes
+# live in host memory at both ends, and the group layout below — an
+# (n, values-per-group) array — would be padded to (n, 128) lanes by a
+# TPU's tiled layout.
 # ---------------------------------------------------------------------------
 
 def _bit_group(width: int) -> tuple[int, int]:
@@ -127,46 +131,61 @@ def _bit_group(width: int) -> tuple[int, int]:
     return L // width, L // 8
 
 
-def pack_bits(plane: jax.Array, width: int) -> jax.Array:
+def _value_dtype(width: int) -> np.dtype:
+    """Narrowest unsigned dtype holding a width-bit value."""
+    return np.dtype(np.uint8 if width <= 8 else
+                    np.uint16 if width <= 16 else np.uint32)
+
+
+def _field(src: np.ndarray, right: int, nbits: int, dtype,
+           left: int) -> np.ndarray:
+    """``((src >> right) & (2^nbits - 1)) << left`` as ``dtype``, in
+    place after the first shift, so one temporary is live at a time."""
+    piece = src >> right
+    piece &= 2**nbits - 1
+    piece = piece.astype(dtype, copy=False)
+    piece <<= left
+    return piece
+
+
+def pack_bits(plane, width: int) -> np.ndarray:
     """Pack a width-bit plane into a dense uint8 byte stream (big-endian
-    bit order). Pure-jnp; used by the wire format.
+    bit order). Used by the wire format.
 
     Works at byte granularity: values are grouped so a group's bits fill
     whole bytes (lcm(width, 8) bits), and each output byte is assembled
     from the <= 2 + 8//width values overlapping it. Peak intermediate is
-    O(n) — never the old (n, width) bit matrix, which at width=16 was a
+    O(n) — never an (n, width) bit matrix, which at width=16 would be a
     32x blowup over the packed payload.
     """
-    flat = plane.astype(jnp.uint32).ravel()
+    flat = np.asarray(plane).ravel().astype(_value_dtype(width), copy=False)
     n = flat.shape[0]
     gv, gb = _bit_group(width)
     pad = (-n) % gv
     if pad:
-        flat = jnp.pad(flat, (0, pad))
+        flat = np.concatenate([flat, np.zeros(pad, flat.dtype)])
     vals = flat.reshape(-1, gv)
-    out_cols = []
+    out = np.zeros((vals.shape[0], gb), np.uint8)
     for b in range(gb):
         lo_bit, hi_bit = 8 * b, 8 * b + 8
-        acc = jnp.zeros((vals.shape[0],), jnp.uint32)
         for i in range(gv):
             v_lo, v_hi = i * width, (i + 1) * width
             o_lo, o_hi = max(lo_bit, v_lo), min(hi_bit, v_hi)
             if o_lo >= o_hi:
                 continue
             nbits = o_hi - o_lo
-            piece = (vals[:, i] >> (v_hi - o_hi)) & jnp.uint32(2**nbits - 1)
-            acc = acc | (piece << (hi_bit - o_hi))
-        out_cols.append(acc.astype(jnp.uint8))
-    by = jnp.stack(out_cols, axis=1).ravel()
-    return by[: -(-n * width // 8)]
+            out[:, b] |= _field(vals[:, i], v_hi - o_hi, nbits, np.uint8,
+                                hi_bit - o_hi)
+    return out.reshape(-1)[: -(-n * width // 8)]
 
 
-def unpack_bits(packed: jax.Array, width: int, n_elements: int) -> jax.Array:
+def unpack_bits(packed, width: int, n_elements: int) -> np.ndarray:
     """Inverse of :func:`pack_bits`; returns uint32 values in [0, 2^w).
     Byte-granular like :func:`pack_bits`: O(n) peak intermediates.
     A payload too short for ``n_elements`` values raises (a truncated
     wire payload must never decode to silent zeros); extra trailing
     bytes are ignored."""
+    packed = np.asarray(packed, np.uint8).ravel()
     need = -(-n_elements * width // 8)
     if packed.shape[0] < need:
         raise ValueError(
@@ -174,22 +193,21 @@ def unpack_bits(packed: jax.Array, width: int, n_elements: int) -> jax.Array:
             f"for {n_elements} width-{width} values")
     gv, gb = _bit_group(width)
     groups = -(-n_elements // gv)
-    by = packed[:need].astype(jnp.uint32)
+    by = packed[:need]
     pad = groups * gb - need
     if pad:
-        by = jnp.pad(by, (0, pad))
+        by = np.concatenate([by, np.zeros(pad, np.uint8)])
     bys = by.reshape(groups, gb)
-    cols = []
+    vdt = _value_dtype(width)
+    out = np.zeros((groups, gv), vdt)
     for i in range(gv):
         v_lo, v_hi = i * width, (i + 1) * width
-        acc = jnp.zeros((groups,), jnp.uint32)
         for b in range(gb):
             lo_bit, hi_bit = 8 * b, 8 * b + 8
             o_lo, o_hi = max(lo_bit, v_lo), min(hi_bit, v_hi)
             if o_lo >= o_hi:
                 continue
             nbits = o_hi - o_lo
-            piece = (bys[:, b] >> (hi_bit - o_hi)) & jnp.uint32(2**nbits - 1)
-            acc = acc | (piece << (v_hi - o_hi))
-        cols.append(acc)
-    return jnp.stack(cols, axis=1).ravel()[:n_elements]
+            out[:, i] |= _field(bys[:, b], hi_bit - o_hi, nbits, vdt,
+                                v_hi - o_hi)
+    return out.reshape(-1)[:n_elements].astype(np.uint32, copy=False)

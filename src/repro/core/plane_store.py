@@ -17,7 +17,8 @@ range, slice info) lives in :class:`TensorSlot` views.
 Upgrades (eq. 4)
 ----------------
 ``ingest([(tensor_idx, plane), ...])`` assembles one flat plane buffer
-plus a per-block int32 shift table and issues ONE batched
+plus a segment table (first block and shift per run of tensors) and
+issues ONE batched
 ``plane_or_segments`` Pallas launch per container dtype — O(1) in the
 number of tensors, vs. the old one-``pallas_call``-per-tensor loop.
 Block alignment is what makes the per-block shift well defined: a block
@@ -352,47 +353,37 @@ class PlaneStore:
             idxs.sort(key=lambda i: self.slots[i].offset)
             total = sum(self.slots[i].padded for i in idxs)
             full = total == buf.shape[0]
-            shifts = np.empty((total // self.block,), np.int32)
+            # segment table: one (first block, shift) entry per run of
+            # consecutive tensors sharing a shift — a uniform schedule
+            # collapses a whole stage to a single entry
+            starts: list[int] = []
+            seg_shifts: list[int] = []
+            plane_np = np.zeros((total,), dtype=buf.dtype)
             pos = 0
             for idx in idxs:
                 t = self.slots[idx]
                 sh = next_plane_shift(t.schedule, self.received[idx])
-                shifts[pos // self.block:(pos + t.padded) // self.block] = sh
+                if not seg_shifts or seg_shifts[-1] != sh:
+                    starts.append(pos // self.block)
+                    seg_shifts.append(sh)
+                # planes are assembled on the host, the DMA landing zone:
+                # one memcpy pass and one upload, whatever the backend
+                plane_np[pos:pos + t.size] = (
+                    np.asarray(items[idx]).reshape(-1))
                 pos += t.padded
-            shifts = (jnp.asarray(shifts) if self.device is None
-                      else jax.device_put(shifts, self.device))
-            # Plane assembly: on an accelerator, keep device-resident
-            # planes (engine path) on device — pad+concat is cheap XLA
-            # work and avoids a blocking D2H+H2D round trip. On the CPU
-            # backend host assembly is the DMA landing zone (one memcpy
-            # pass + one upload) and measurably faster. The ACCUMULATOR
-            # never leaves the device on either path.
-            if jax.default_backend() != "cpu":
-                parts = []
-                for idx in idxs:
-                    t = self.slots[idx]
-                    p = jnp.asarray(items[idx]).reshape(-1).astype(buf.dtype)
-                    if t.padded != t.size:
-                        p = jnp.pad(p, (0, t.padded - t.size))
-                    parts.append(p)
-                plane = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-                if self.device is not None:
-                    plane = jax.device_put(plane, self.device)
+            table = (np.asarray(starts, np.int32),
+                     np.asarray(seg_shifts, np.int32))
+            if self.device is None:
+                seg_starts, shifts = (jnp.asarray(a) for a in table)
+                plane = jnp.asarray(plane_np)
             else:
-                plane_np = np.zeros((total,), dtype=buf.dtype)
-                pos = 0
-                for idx in idxs:
-                    t = self.slots[idx]
-                    plane_np[pos:pos + t.size] = (
-                        np.asarray(items[idx]).reshape(-1))
-                    pos += t.padded
-                plane = (jnp.asarray(plane_np) if self.device is None
-                         else jax.device_put(plane_np, self.device))
+                seg_starts, shifts, plane = jax.device_put(
+                    (*table, plane_np), self.device)
             if full:
                 # Whole buffer touched (the common full-stage upgrade):
                 # segments are dense by layout, no gather/scatter needed.
                 self.buffers[dt] = ops.plane_or_segments(
-                    buf, plane, shifts, block=self.block)
+                    buf, plane, seg_starts, shifts, block=self.block)
             else:
                 # Sparse shipment: sweep only the touched blocks —
                 # O(touched bytes), not O(whole per-dtype buffer).
@@ -404,7 +395,7 @@ class PlaneStore:
                                    self.slots[i].offset + self.slots[i].padded]
                                for i in idxs]))
                 out = ops.plane_or_segments(
-                    compact, plane, shifts, block=self.block)
+                    compact, plane, seg_starts, shifts, block=self.block)
                 segs, pos = [], 0
                 for idx in idxs:
                     t = self.slots[idx]

@@ -48,7 +48,7 @@ class TensorPlanes:
     hi: jax.Array
     shape: tuple
     orig_dtype: Any
-    planes: list[jax.Array]  # MSB-first, len == n_planes
+    planes: list[np.ndarray]  # MSB-first, len == n_planes, host memory
     slice_axis: int | None = None
     slice_idx: int = 0
     n_slices: int = 1
@@ -128,8 +128,18 @@ def divide(params, policy: DivisionPolicy | None = None) -> ProgressiveModel:
         for slice_axis, idx, n_slices, sub in slices:
             plan = policy.plan(path, sub.shape, arr.dtype,
                                slice_idx=None if slice_axis is None else idx)
+            # Eager ops allocate their outputs when dispatched, so the
+            # temporaries of everything not yet run are live at once:
+            # wait for the codes before splitting. Planes are the wire's
+            # source, not the device's: each moves to host memory as soon
+            # as it is split (np.asarray waits for it), so the accelerator
+            # never holds a whole model's planes next to its params.
             qt = quantize(sub, plan.schedule.bits)
-            planes = bitplanes.split(qt, plan.schedule.widths)
+            qt.q.block_until_ready()
+            widths = plan.schedule.widths
+            planes = [np.asarray(bitplanes.split_plane(qt.q, qt.bits,
+                                                       widths, m))
+                      for m in range(1, len(widths) + 1)]
             tensors.append(
                 TensorPlanes(
                     path=path,

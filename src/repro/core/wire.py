@@ -58,7 +58,6 @@ import struct
 import zlib
 
 import numpy as np
-import jax.numpy as jnp
 
 from repro.core import bitplanes, entropy
 from repro.core.progressive import ProgressiveModel
@@ -202,8 +201,7 @@ def encode_stage(model: ProgressiveModel, s: int) -> bytes:
     for idx, plane in model.stage(s):
         t = model.tensors[idx]
         w = t.plan.schedule.widths[s - 1]
-        packed = bitplanes.pack_bits(jnp.asarray(plane), w)
-        chunks.append(np.asarray(packed).tobytes())
+        chunks.append(bitplanes.pack_bits(plane, w).tobytes())
     return b"".join(chunks)
 
 
@@ -214,8 +212,7 @@ def encode_unit(model: ProgressiveModel, t_idx: int, p: int,
     unit is never larger than the raw packed plane + FRAME_BYTES."""
     t = model.tensors[t_idx]
     w = t.plan.schedule.widths[p]
-    packed = np.asarray(
-        bitplanes.pack_bits(jnp.asarray(t.planes[p]), w)).tobytes()
+    packed = bitplanes.pack_bits(t.planes[p], w).tobytes()
     if entropy_coded:
         mode, body = entropy.encode(packed)
     else:
@@ -453,5 +450,5 @@ def decode_plane(payload: bytes, width: int, n_elements: int,
         raise WireFormatError(
             f"plane payload is {len(payload)} bytes, expected {raw_len} "
             f"({n_elements} elements x {width} bits)")
-    packed = jnp.asarray(np.frombuffer(payload, dtype=np.uint8))
-    return np.asarray(bitplanes.unpack_bits(packed, width, n_elements))
+    return bitplanes.unpack_bits(np.frombuffer(payload, dtype=np.uint8),
+                                 width, n_elements)

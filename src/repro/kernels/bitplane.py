@@ -30,9 +30,21 @@ def _or_kernel(acc_ref, plane_ref, o_ref, *, shift: int):
     o_ref[...] = (a | (p << shift)).astype(o_ref.dtype)
 
 
-def _or_segments_kernel(shift_ref, acc_ref, plane_ref, o_ref):
-    # shift_ref is the scalar-prefetch table (SMEM): one shift per block.
-    sh = shift_ref[pl.program_id(0)].astype(jnp.uint32)
+def _or_segments_kernel(starts_ref, shifts_ref, acc_ref, plane_ref, o_ref,
+                        *, search_steps: int):
+    # starts_ref/shifts_ref are the scalar-prefetch segment table (SMEM):
+    # segment j covers blocks [starts[j], starts[j+1]) at shift shifts[j].
+    # A binary search finds the last segment starting at or before this
+    # block: ceil(log2(n_segments)) scalar steps per grid step.
+    i = pl.program_id(0)
+    lo = jnp.int32(0)
+    hi = jnp.int32(starts_ref.shape[0])
+    for _ in range(search_steps):
+        mid = (lo + hi) // 2
+        right = starts_ref[mid] <= i
+        lo = jnp.where(right, mid, lo)
+        hi = jnp.where(right, hi, mid)
+    sh = shifts_ref[lo].astype(jnp.uint32)
     a = acc_ref[...].astype(jnp.uint32)
     p = plane_ref[...].astype(jnp.uint32)
     o_ref[...] = (a | (p << sh)).astype(o_ref.dtype)
@@ -86,26 +98,29 @@ def plane_or(acc: jax.Array, plane: jax.Array, *, shift: int,
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def plane_or_segments(acc: jax.Array, plane: jax.Array, shifts: jax.Array, *,
-                      block: int = 1024, interpret: bool = False) -> jax.Array:
+def plane_or_segments(acc: jax.Array, plane: jax.Array, seg_starts: jax.Array,
+                      seg_shifts: jax.Array, *, block: int = 1024,
+                      interpret: bool = False) -> jax.Array:
     """Batched eq. (4) over a *flat concatenated* accumulator buffer.
 
     One launch upgrades every tensor of a model at once: ``acc`` and
     ``plane`` are 1-D buffers in which each tensor occupies a
-    block-aligned segment (see ``core/plane_store.py``), and ``shifts``
-    is an int32 ``(n_blocks,)`` table giving the left shift of the block
-    each grid step processes. The table rides in as a scalar-prefetch
-    operand (SMEM), so the per-block shift is known before the block's
-    DMA issues — the grid stays a single dense 1-D sweep and the whole
-    upgrade is ONE ``pallas_call`` instead of one per tensor.
+    block-aligned segment (see ``core/plane_store.py``). The segment
+    table gives, per run of blocks sharing one left shift, its first
+    block (``seg_starts``, int32, ascending, starting at 0) and that
+    shift (``seg_shifts``, int32). The table rides in as scalar-prefetch
+    operands (SMEM), so each block's shift is known before its DMA
+    issues — the grid stays a single dense 1-D sweep and the whole
+    upgrade is ONE ``pallas_call`` instead of one per tensor. The table
+    grows with the number of segments, not with the buffer: a model has
+    a handful of tensors but up to millions of blocks, and SMEM holds
+    1 MiB.
 
     Blocks with nothing arriving carry a zero plane segment: OR with 0
     is the identity at any shift, so no masking is needed.
 
     ``block`` must be a multiple of 128 (lane width); both buffers must
-    be a multiple of ``block`` long. On a real pod the table is one int
-    per 1024 elements — for very large shards raise ``block`` to keep
-    the table comfortably in SMEM.
+    be a multiple of ``block`` long.
     """
     if acc.ndim != 1 or plane.ndim != 1:
         raise ValueError("plane_or_segments operates on flat 1-D buffers")
@@ -117,29 +132,32 @@ def plane_or_segments(acc: jax.Array, plane: jax.Array, shifts: jax.Array, *,
     if plane.shape[0] != n:
         raise ValueError(
             f"plane length {plane.shape[0]} != acc length {n}")
-    if shifts.shape[0] != n // block:
+    if seg_starts.ndim != 1 or seg_starts.shape != seg_shifts.shape \
+            or seg_starts.shape[0] < 1:
         raise ValueError(
-            f"shift table has {shifts.shape[0]} entries, expected "
-            f"{n // block} (one per block)")
+            f"segment table needs matching non-empty 1-D starts/shifts, "
+            f"got {seg_starts.shape} and {seg_shifts.shape}")
     rows = block // 128
     a2 = acc.reshape(-1, 128)
     p2 = plane.reshape(-1, 128)
     n_blocks = n // block
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((rows, 128), lambda i, s: (i, 0)),
-            pl.BlockSpec((rows, 128), lambda i, s: (i, 0)),
+            pl.BlockSpec((rows, 128), lambda i, st, sh: (i, 0)),
+            pl.BlockSpec((rows, 128), lambda i, st, sh: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((rows, 128), lambda i, s: (i, 0)),
+        out_specs=pl.BlockSpec((rows, 128), lambda i, st, sh: (i, 0)),
     )
+    n_segs = seg_starts.shape[0]
     out = pl.pallas_call(
-        _or_segments_kernel,
+        functools.partial(_or_segments_kernel,
+                          search_steps=(n_segs - 1).bit_length()),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(a2.shape, acc.dtype),
         interpret=interpret,
-    )(shifts.astype(jnp.int32), a2, p2)
+    )(seg_starts.astype(jnp.int32), seg_shifts.astype(jnp.int32), a2, p2)
     return out.reshape(-1)
 
 

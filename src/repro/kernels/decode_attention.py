@@ -68,8 +68,8 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (G, hd), pre-scaled
     k = k_ref[0, 0].astype(jnp.float32)          # (bs, hd)
     v = v_ref[0, 0].astype(jnp.float32)          # (bs, hd)
-    kpos = pos_ref[...]                          # (1, bs) int32, this slot
-    qpos = qpos_ref[0, 0]                        # scalar, this slot
+    kpos = pos_ref[0, 0]                         # (1, bs) int32, this slot
+    qpos = qpos_ref[0]                           # (1, 1) int32, this slot
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bs)
@@ -134,18 +134,21 @@ def flash_decode(
     qg = q.reshape(B, Kh, G, hd) * (hd ** -0.5)
     if Gp != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    pos2 = k_pos.reshape(B, S).astype(jnp.int32)
-    qpos2 = q_pos.reshape(B, 1).astype(jnp.int32)
+    # Mosaic wants a block's last two dims to be (8k, 128m) or the whole
+    # array dims: per-slot position rows get unit axes so each block
+    # spans its array's last two dims in full, for any B and bs.
+    pos2 = k_pos.astype(jnp.int32).reshape(B, n_s, 1, bs)
+    qpos2 = q_pos.astype(jnp.int32).reshape(B, 1, 1)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_s=n_s, window=window, softcap=softcap),
         grid=(B, Kh, n_s),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, s: (b, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, h, s: (b, 0, 0)),
             pl.BlockSpec((1, 1, Gp, hd), lambda b, h, s: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, bs), lambda b, h, s: (b, s)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, h, s: (b, s, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Gp, hd), lambda b, h, s: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kh, Gp, hd), q.dtype),

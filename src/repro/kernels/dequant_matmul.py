@@ -52,7 +52,10 @@ def _kernel(x_ref, q_ref, scale_ref, off_ref, o_ref, acc_ref, *, n_k: int):
     scale = scale_ref[0, 0]
     off = off_ref[0, 0]
     # eq. (5) on the weight tile, in-register: uint -> fp32 affine.
-    w = q_ref[...].astype(jnp.float32) * scale + off
+    # Mosaic has no unsigned -> float cast, so widen to int32 first:
+    # exact for the uint8/uint16 containers (k <= 16); uint32 codes at
+    # or above 2^31 would wrap negative.
+    w = q_ref[...].astype(jnp.int32).astype(jnp.float32) * scale + off
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container everything runs with ``interpret=True`` (the
-kernel body executes in Python, bit-exact with the TPU lowering's
-semantics); on a real TPU the same calls compile to Mosaic. The switch
-is automatic via the default backend — callers never pass ``interpret``.
+On a TPU the calls compile to Mosaic; on any other backend (the CPU
+test suite) they run with ``interpret=True`` (the kernel body executes
+in Python, bit-exact with the TPU lowering's semantics). The switch is
+automatic via the default backend — callers never pass ``interpret``.
 
 ``LAUNCH_COUNTS`` tallies kernel dispatches at the *call site* (outside
 jit), which is what the upgrade-latency benchmark uses to prove a
@@ -45,6 +45,9 @@ def _count(name: str) -> None:
 
 
 def _interpret_default() -> bool:
+    """The one backend switch: Mosaic kernels on a TPU, interpret mode
+    (or, for the model's attention entry points, the jnp oracles)
+    everywhere else."""
     return jax.default_backend() != "tpu"
 
 
@@ -59,18 +62,17 @@ def dequant_matmul(x, q, scale, offset, **kw):
 
 @functools.lru_cache(maxsize=None)
 def _sharded_dqm(mesh, axis: str, interpret: bool):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    # check_rep=False is required: pallas_call has no replication rule,
-    # and the kernel computes no cross-shard reductions anyway (K stays
-    # whole per shard).
-    return jax.jit(shard_map(
+    # check_vma=False is required: pallas_call has no rule for varying
+    # manual axes, and the kernel computes no cross-shard reductions
+    # anyway (K stays whole per shard).
+    return jax.jit(jax.shard_map(
         functools.partial(_dqm.dequant_matmul, interpret=interpret),
         mesh=mesh,
         in_specs=(P(), P(None, axis), P(), P()),
         out_specs=P(None, axis),
-        check_rep=False))
+        check_vma=False))
 
 
 def sharded_dequant_matmul(x, q, scale, offset, *, mesh, axis: str = "model"):
@@ -78,13 +80,12 @@ def sharded_dequant_matmul(x, q, scale, offset, *, mesh, axis: str = "model"):
     N over ``mesh``'s ``axis``; x/scale/offset replicated. One kernel
     launch *per shard* under ``shard_map`` — each shard dequantizes and
     multiplies its own (K, N/n) accumulator columns, and the output
-    comes back (M, N) sharded on N (XLA overlaps any consumer-driven
-    gather against the other shards' dequant work). Bit-identical to
-    the single-device kernel: the K contraction is never sharded, so no
-    partial-sum all-reduce ever reorders float adds. This is the
-    shard_map half of the sharded serving story; the engines' model
-    path uses jit-with-shardings (``models.common.serving_mesh``)
-    instead, which XLA partitions from the same specs."""
+    comes back (M, N) sharded on N. Bit-identical to the single-device
+    kernel: the K contraction is never sharded, so no partial-sum
+    all-reduce ever reorders float adds. The model's dense dispatch
+    (``models.common.dense``) routes here whenever a serving mesh is
+    active: GSPMD cannot partition a Mosaic kernel, so a sharded
+    program must place each launch itself."""
     _count("sharded_dequant_matmul")
     return _sharded_dqm(mesh, axis, _interpret_default())(
         x, q, scale, offset)
@@ -96,10 +97,10 @@ def plane_or(acc, plane, *, shift, **kw):
     return _bp.plane_or(acc, plane, shift=shift, **kw)
 
 
-def plane_or_segments(acc, plane, shifts, **kw):
+def plane_or_segments(acc, plane, seg_starts, seg_shifts, **kw):
     _count("plane_or_segments")
     kw.setdefault("interpret", _interpret_default())
-    return _bp.plane_or_segments(acc, plane, shifts, **kw)
+    return _bp.plane_or_segments(acc, plane, seg_starts, seg_shifts, **kw)
 
 
 def plane_extract(q, *, bits, before, width, **kw):
@@ -130,7 +131,7 @@ def decode_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
     tuning knobs like ``bs`` belong to :func:`flash_decode` callers,
     and the two backends must accept identical calls.)"""
     _count("decode_attention")
-    if jax.default_backend() == "tpu":
+    if not _interpret_default():
         return _da.flash_decode(
             q, k, v, k_pos, q_pos, window=window, softcap=softcap,
             interpret=False
@@ -163,7 +164,7 @@ def verify_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
     this backend. Same no-pass-through-kwargs rule as
     :func:`decode_attention`."""
     _count("verify_attention")
-    if jax.default_backend() == "tpu":
+    if not _interpret_default():
         return _va.flash_verify(
             q, k, v, k_pos, q_pos, window=window, softcap=softcap,
             interpret=False
@@ -190,7 +191,7 @@ def prefill_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
     ``kernels/ref.flash_prefill_ref``. Same no-pass-through-kwargs rule
     as :func:`decode_attention`."""
     _count("prefill_attention")
-    if jax.default_backend() == "tpu":
+    if not _interpret_default():
         return _va.flash_verify(
             q, k, v, k_pos, q_pos, window=window, softcap=softcap,
             interpret=False

@@ -55,16 +55,15 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (R, hd), pre-scaled
     k = k_ref[0, 0].astype(jnp.float32)          # (bs, hd)
     v = v_ref[0, 0].astype(jnp.float32)          # (bs, hd)
-    kpos = pos_ref[...]                          # (1, bs) int32, this slot
-    qpos = qpos_ref[...]                         # (1, R) int32 per-row pos
+    kpos = pos_ref[0, 0]                         # (1, bs) int32, this slot
+    qp = qpos_ref[0]                             # (R, 1) int32 per-row pos
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (R, bs)
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
-    # per-row causality: row r is the query at position qpos[r]; a
-    # negative qpos (draft padding / free slot) masks the entire row
-    qp = qpos.reshape(-1, 1)                     # (R, 1)
+    # per-row causality: row r is the query at position qp[r]; a
+    # negative qp (draft padding / free slot) masks the entire row
     valid = (kpos >= 0) & (kpos <= qp) & (qp >= 0)
     if window:
         valid = valid & (kpos > qp - window)
@@ -126,17 +125,20 @@ def flash_verify(
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
         qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, Rp - R)),
                             constant_values=-1)
-    pos2 = k_pos.reshape(B, S).astype(jnp.int32)
+    # unit axes make every position block span its array's last two
+    # dims in full, which Mosaic accepts for any B, Rp and bs
+    pos2 = k_pos.astype(jnp.int32).reshape(B, n_s, 1, bs)
+    qpos_rows = qpos_rows[:, :, None]                           # (B, Rp, 1)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_s=n_s, window=window, softcap=softcap),
         grid=(B, Kh, n_s),
         in_specs=[
-            pl.BlockSpec((1, Rp), lambda b, h, s: (b, 0)),
+            pl.BlockSpec((1, Rp, 1), lambda b, h, s: (b, 0, 0)),
             pl.BlockSpec((1, 1, Rp, hd), lambda b, h, s: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, bs), lambda b, h, s: (b, s)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, h, s: (b, s, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Rp, hd), lambda b, h, s: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kh, Rp, hd), q.dtype),

@@ -1,7 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import: jax locks the device count on first
-# init, and the production-mesh dry-run needs 512 placeholder devices.
+os.environ.update(JAX_PLATFORMS="cpu",
+                  XLA_FLAGS="--xla_force_host_platform_device_count=512")
+# ^ MUST precede every other import: jax locks the platform and device
+# count on first init, and the production-mesh dry-run needs 512
+# placeholder host devices. The CPU pin also keeps this process and the
+# per-combo children it spawns off any attached accelerator, which
+# belongs to one process at a time.
 """Multi-pod dry-run: lower + compile every (arch x input-shape) on the
 production meshes, prove the sharding config is coherent, and dump the
 roofline source terms.

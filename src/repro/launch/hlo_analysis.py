@@ -130,17 +130,9 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def normalize_cost_analysis(ca) -> dict:
-    """``Compiled.cost_analysis()`` returns a one-element list of dicts
-    on jax<=0.4.x and a plain dict on newer releases; accept both."""
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def raw_counts(compiled) -> dict:
     """Additive per-device counters from one compiled module."""
-    ca = normalize_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     stats = parse_collectives(compiled.as_text())
     return {

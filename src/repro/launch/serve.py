@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core import wire
 from repro.core.progressive import divide
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.transmission import Session, get_scenario, list_scenarios
 from repro.transmission.simulator import BandwidthTrace
@@ -194,6 +195,7 @@ def main() -> None:
                     help="seed for the fault profile and retry jitter "
                          "(default: --seed)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.metrics:
         from repro import obs

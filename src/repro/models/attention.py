@@ -24,7 +24,7 @@ from jax import lax
 
 from repro.kernels import ops
 from repro.models.common import (ArchConfig, apply_norm, norm_init,
-                                 activation, dense, dense_init)
+                                 activation, dense, dense_init, launch)
 
 NEG_INF = -1e30
 
@@ -396,8 +396,8 @@ def self_attention(
             k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         attend = (ops.prefill_attention if mode == "prefill_chunk"
                   else ops.verify_attention)
-        out = attend(
-            q, k_cache, v_cache, k_pos.astype(jnp.int32), tok_pos,
+        out = launch(
+            attend, q, k_cache, v_cache, k_pos.astype(jnp.int32), tok_pos,
             window=window,
         )                                                      # (B, T, H, hd)
         new_cache = {"k": k_cache, "v": v_cache}
@@ -430,9 +430,9 @@ def self_attention(
             # the kernel masks k_pos > q_pos per slot; stale entries
             # beyond each slot's position never contribute
             k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        out = ops.decode_attention(
-            q[:, 0], k_cache, v_cache, k_pos.astype(jnp.int32), pos_vec,
-            window=window,
+        out = launch(
+            ops.decode_attention, q[:, 0], k_cache, v_cache,
+            k_pos.astype(jnp.int32), pos_vec, window=window,
         )[:, None]                                             # (B, 1, H, hd)
         new_cache = {"k": k_cache, "v": v_cache}
     return dense(out.reshape(B, Tq, -1), p["wo"], dtype=cfg.dtype), new_cache
@@ -459,13 +459,15 @@ def cross_attention(cfg: ArchConfig, p, x: jax.Array, enc_kv, *,
         # non-causal: q_pos = Tv admits every memory slot for every slot
         if Tq == 1:
             q_pos = jnp.full((B,), Tv, jnp.int32)
-            out = ops.decode_attention(
-                q[:, 0], enc_kv["k"], enc_kv["v"], k_pos, q_pos, window=0,
+            out = launch(
+                ops.decode_attention, q[:, 0], enc_kv["k"], enc_kv["v"],
+                k_pos, q_pos, window=0,
             )[:, None]
         else:
             q_pos = jnp.full((B, Tq), Tv, jnp.int32)
-            out = ops.verify_attention(
-                q, enc_kv["k"], enc_kv["v"], k_pos, q_pos, window=0,
+            out = launch(
+                ops.verify_attention, q, enc_kv["k"], enc_kv["v"], k_pos,
+                q_pos, window=0,
             )
     else:
         Tv = enc_kv["k"].shape[1]

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -29,14 +30,31 @@ def serving_mesh(mesh):
     replicated, each matmul sees a replicated input against a weight
     sharded on a non-contraction dim (see ``serving_spec_for_param``) —
     the only collectives are output all-gathers, pure data movement,
-    bit-exact. The engines wrap their jitted model entry points in this
-    context (``PrecisionManagedEngine._meshed``); with no mesh active
-    the helpers are byte-for-byte the single-device code path."""
+    bit-exact. Quantized weights take the same route through
+    ``ops.sharded_dequant_matmul`` (one kernel launch per shard). The
+    engines wrap their jitted model entry points in this context
+    (``PrecisionManagedEngine._meshed``); with no mesh active the
+    helpers are byte-for-byte the single-device code path."""
     _SERVING_MESH.append(mesh)
     try:
         yield
     finally:
         _SERVING_MESH.pop()
+
+
+def launch(kernel, *args, **static):
+    """Call a kernel entry point of :mod:`repro.kernels.ops`. Under an
+    active serving mesh the call becomes one launch per device on
+    replicated operands: GSPMD cannot partition a Mosaic kernel, so
+    every kernel in a sharded program sits inside a ``shard_map``.
+    ``static`` holds the entry point's keyword options."""
+    mesh = _SERVING_MESH[-1]
+    fn = functools.partial(kernel, **static)
+    if mesh is None:
+        return fn(*args)
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 def _pin_replicated(y: jax.Array) -> jax.Array:
@@ -288,14 +306,24 @@ def masked_q(w: QuantizedTensor, q: jax.Array | None = None,
     return (q >> shift) << shift
 
 
+def _dequant_matmul(x: jax.Array, q: jax.Array, scale, offset) -> jax.Array:
+    """The fused kernel on a 2-D ``x``. Under a serving mesh whose model
+    axis divides N it runs once per shard on that shard's own output
+    columns (no weight crosses a chip); otherwise once per device."""
+    mesh = _SERVING_MESH[-1]
+    if mesh is not None and q.shape[-1] % mesh.shape["model"] == 0:
+        return ops.sharded_dequant_matmul(x, q, scale, offset, mesh=mesh)
+    return launch(ops.dequant_matmul, x, q, scale, offset)
+
+
 def dense(x: jax.Array, w, *, dtype) -> jax.Array:
     """``x @ w`` with ``w`` either a float array (cast to ``dtype``,
     plain matmul) or a QuantizedTensor (fused dequant-matmul; f32
     accumulation, output cast to ``dtype``). x: (..., K); w: (K, N)."""
     if isinstance(w, QuantizedTensor):
         lead = x.shape[:-1]
-        y = ops.dequant_matmul(x.reshape(-1, x.shape[-1]), masked_q(w),
-                               w.scale, w.offset)
+        y = _dequant_matmul(x.reshape(-1, x.shape[-1]), masked_q(w),
+                            w.scale, w.offset)
         return _pin_replicated(y.reshape(*lead, w.q.shape[-1])).astype(dtype)
     return _pin_replicated(x @ w.astype(dtype))
 
@@ -311,8 +339,8 @@ def expert_dense(x: jax.Array, w, *, dtype) -> jax.Array:
         for e in range(E):
             qe = masked_q(w, w.q[e],
                           None if w.keep_bits is None else w.keep_bits[e])
-            ye = ops.dequant_matmul(x[:, e].reshape(B * C, d), qe,
-                                    w.scale[e], w.offset[e])
+            ye = _dequant_matmul(x[:, e].reshape(B * C, d), qe,
+                                 w.scale[e], w.offset[e])
             outs.append(ye.reshape(B, C, -1))
         return _pin_replicated(jnp.stack(outs, axis=1)).astype(dtype)
     return _pin_replicated(jnp.einsum("becd,edf->becf", x, w.astype(dtype)))

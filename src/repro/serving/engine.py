@@ -626,6 +626,15 @@ class SlotPoolEngine(PrecisionManagedEngine):
         # flush for deferred first-token emission)
         self._last_tok = jnp.zeros((n_slots, 1), jnp.int32)
         self._first_cap = jnp.zeros((n_slots,), jnp.int32)
+        if mesh is not None:
+            # the pooled state starts replicated on the mesh, as the
+            # jitted steps return it: single-device inputs on the first
+            # step would compile a second executable for every later one
+            (self.caches, self.pos, self.last_logits, self._last_tok,
+             self._first_cap) = jax.device_put(
+                (self.caches, self.pos, self.last_logits, self._last_tok,
+                 self._first_cap),
+                jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
         self._recurrent_cycle_keys = [
             f"{j}_{kind}" for j, kind in enumerate(model.cfg.cycle)
             if kind in _RECURRENT_KINDS]

@@ -76,6 +76,21 @@ def test_pack_unpack_roundtrip(vals, width):
     assert (np.asarray(out) == vals).all()
 
 
+@pytest.mark.parametrize("width", [1, 2, 3, 5, 7, 8, 11, 16])
+def test_pack_bits_matches_bitstream_reference(width):
+    """The wire layout: each value's ``width`` bits, MSB first, end to
+    end, cut into bytes MSB first (``np.packbits`` order), the last byte
+    zero-padded."""
+    vals = np.random.default_rng(width).integers(
+        0, 2**width, size=1001).astype(np.uint32)
+    bits = (vals[:, None] >> np.arange(width - 1, -1, -1)) & 1
+    want = np.packbits(bits.astype(np.uint8).ravel())
+    np.testing.assert_array_equal(np.asarray(bitplanes.pack_bits(vals, width)),
+                                  want)
+    np.testing.assert_array_equal(
+        np.asarray(bitplanes.unpack_bits(want, width, vals.size)), vals)
+
+
 def test_width_validation():
     with pytest.raises(ValueError):
         bitplanes.validate_widths(8, (2, 2))  # sums to 4

@@ -6,6 +6,8 @@ partial-stage arrivals, mixed container-dtype models, the batched
 segment-OR kernel vs the per-tensor kernel, and the byte-granular
 wire packing (no O(n*width) intermediate blowup).
 """
+import tracemalloc
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -147,27 +149,30 @@ def test_copy_isolates_dirty_state(params):
 # batched segment kernel
 # ---------------------------------------------------------------------------
 
-def test_plane_or_segments_matches_per_tensor_kernel():
+@pytest.mark.parametrize("sizes,seg_shifts", [
+    ([300, 128, 1000], (14, 10, 8)),  # padded segments of 2, 1, 4 blocks
+    ([700], (6,)),
+    ([256, 1, 513, 90, 1024, 3, 77], (14, 12, 12, 10, 8, 6, 0)),
+])
+def test_plane_or_segments_matches_per_tensor_kernel(sizes, seg_shifts):
     rng = np.random.default_rng(0)
     block = 256
-    sizes = [300, 128, 1000]  # -> padded segments of 2, 1, 4 blocks
     offs, cur = [], 0
     for s in sizes:
         offs.append(cur)
         cur += -(-s // block) * block
     acc = jnp.asarray(rng.integers(0, 2**8, size=cur), jnp.uint16)
     plane_flat = jnp.zeros((cur,), jnp.uint16)
-    shifts = np.zeros((cur // block,), np.int32)
     per_tensor = []
-    planes = []
-    for (off, s, sh) in zip(offs, sizes, (14, 10, 8)):
+    for (off, s, sh) in zip(offs, sizes, seg_shifts):
         p = jnp.asarray(rng.integers(0, 4, size=s), jnp.uint16)
-        planes.append(p)
         plane_flat = plane_flat.at[off:off + s].set(p)
-        shifts[off // block: (off + -(-s // block) * block) // block] = sh
         per_tensor.append(plane_or(acc[off:off + s], p, shift=sh,
                                    interpret=True))
-    out = plane_or_segments(acc, plane_flat, jnp.asarray(shifts),
+    # segment table: first block of each segment and its shift
+    starts = jnp.asarray([off // block for off in offs], jnp.int32)
+    shifts = jnp.asarray(seg_shifts, jnp.int32)
+    out = plane_or_segments(acc, plane_flat, starts, shifts,
                             block=block, interpret=True)
     for off, s, want in zip(offs, sizes, per_tensor):
         np.testing.assert_array_equal(np.asarray(out[off:off + s]),
@@ -238,40 +243,39 @@ def test_next_plane_shift_exhaustion():
 # byte-granular packing: no O(n*width) intermediates
 # ---------------------------------------------------------------------------
 
-def _max_intermediate_elems(fn, *args):
-    jaxpr = jax.make_jaxpr(fn)(*args)
-    sizes = [1]
-    for eqn in jaxpr.jaxpr.eqns:
-        for v in eqn.outvars:
-            if hasattr(v.aval, "shape"):
-                sizes.append(int(np.prod(v.aval.shape) or 1))
-    return max(sizes)
+def _peak_alloc_bytes(fn, *args):
+    """Peak bytes NumPy allocates while ``fn`` runs (NumPy reports its
+    buffers to tracemalloc)."""
+    tracemalloc.start()
+    try:
+        fn(*args)
+        return tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
 
 
 @pytest.mark.parametrize("width", [2, 3, 7, 16])
 def test_pack_bits_large_n_no_blowup(width):
     n = 1 << 18
-    vals = jnp.asarray(
-        np.random.default_rng(width).integers(0, 2**width, size=n), jnp.uint32)
+    vals = np.random.default_rng(width).integers(
+        0, 2**width, size=n).astype(np.uint32)
     packed = pack_bits(vals, width)
     assert packed.shape[0] == -(-n * width // 8)
-    np.testing.assert_array_equal(
-        np.asarray(unpack_bits(packed, width, n)), np.asarray(vals))
-    # Peak intermediate stays O(n): the old implementation built an
-    # (n, width) bit matrix plus an 8-wide byte matrix (> 2*n*width).
-    peak = _max_intermediate_elems(lambda v: pack_bits(v, width), vals)
-    assert peak <= 2 * n, peak
-    peak_un = _max_intermediate_elems(
-        lambda p: unpack_bits(p, width, n), packed)
-    assert peak_un <= 2 * n, peak_un
+    np.testing.assert_array_equal(unpack_bits(packed, width, n), vals)
+    # Peak stays O(n): at most two uint32 values per element, where an
+    # (n, width) bit matrix plus an 8-wide byte matrix would be
+    # > n * (width + 8) bytes.
+    peak = _peak_alloc_bytes(pack_bits, vals, width)
+    assert peak <= 2 * vals.nbytes, peak
+    peak_un = _peak_alloc_bytes(unpack_bits, packed, width, n)
+    assert peak_un <= 2 * vals.nbytes, peak_un
     # Truncated payloads must raise, never zero-fill; trailing extra
     # bytes are tolerated.
     with pytest.raises(ValueError):
         unpack_bits(packed[:-1], width, n)
     np.testing.assert_array_equal(
-        np.asarray(unpack_bits(jnp.concatenate(
-            [packed, jnp.zeros(3, packed.dtype)]), width, n)),
-        np.asarray(vals))
+        unpack_bits(np.concatenate([packed, np.zeros(3, packed.dtype)]),
+                    width, n), vals)
 
 
 def test_batched_dequant_bit_identical_to_scalar():

@@ -26,50 +26,57 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.launch.sharding import serving_spec_for_param
 
-MESH = AbstractMesh((("data", 2), ("model", 4)))
-MESH1 = AbstractMesh((("data", 8), ("model", 1)))
+
+@pytest.fixture(scope="module")
+def mesh():
+    return AbstractMesh((2, 4), ("data", "model"))
+
+
+@pytest.fixture(scope="module")
+def mesh1():
+    return AbstractMesh((8, 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
 # spec rules: nothing reduced is ever sharded
 # ---------------------------------------------------------------------------
 
-def test_serving_spec_output_dim_only():
+def test_serving_spec_output_dim_only(mesh):
     # 2-D weight: model axis on the OUTPUT (last) dim, data never used
     assert serving_spec_for_param("decoder/cycles/0_attn/attn/wq",
-                                  (3, 64, 128), MESH) == P(None, None, "model")
-    assert serving_spec_for_param("embed", (160, 64), MESH) == \
+                                  (3, 64, 128), mesh) == P(None, None, "model")
+    assert serving_spec_for_param("embed", (160, 64), mesh) == \
         P(None, "model")
 
 
-def test_serving_spec_never_shards_contractions_or_data():
+def test_serving_spec_never_shards_contractions_or_data(mesh):
     # every returned spec uses ONLY the model axis, only on the last dim
     # or the expert dim — a contraction (any other dim) stays None
     for shape in [(64, 128), (2, 64, 128), (4, 8, 64, 128)]:
         spec = serving_spec_for_param("decoder/cycles/0_attn/mlp/wo",
-                                      shape, MESH)
+                                      shape, mesh)
         assert all(s in (None, "model") for s in spec)
         assert all(s is None for s in spec[:-1])
 
 
-def test_serving_spec_expert_dim_preferred():
+def test_serving_spec_expert_dim_preferred(mesh):
     # MoE bank (R, E, d, f): expert dim (indexed, never contracted)
     spec = serving_spec_for_param("decoder/cycles/0_moe/moe/we_gate",
-                                  (2, 8, 64, 128), MESH)
+                                  (2, 8, 64, 128), mesh)
     assert tuple(spec) == (None, "model", None, None)
     # indivisible E falls back to the output dim, not a contraction
     spec = serving_spec_for_param("decoder/cycles/0_moe/moe/we_up",
-                                  (2, 6, 64, 128), MESH)
+                                  (2, 6, 64, 128), mesh)
     assert tuple(spec)[-1] == "model"
 
 
-def test_serving_spec_replicates_everything_else():
-    assert serving_spec_for_param("final_norm/scale", (64,), MESH) == P()
-    assert serving_spec_for_param("b", (), MESH) == P()
+def test_serving_spec_replicates_everything_else(mesh, mesh1):
+    assert serving_spec_for_param("final_norm/scale", (64,), mesh) == P()
+    assert serving_spec_for_param("b", (), mesh) == P()
     # indivisible output dim -> replicated, never a partial shard
-    assert serving_spec_for_param("w", (64, 30), MESH) == P()
+    assert serving_spec_for_param("w", (64, 30), mesh) == P()
     # degenerate 1-wide model axis -> replicated
-    assert serving_spec_for_param("embed", (160, 64), MESH1) == P()
+    assert serving_spec_for_param("embed", (160, 64), mesh1) == P()
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +237,7 @@ def test_sharded_moe_speculative_and_pool_token_identity():
     out["pool_tokens_equal"] = all(
         p1.tokens[rid] == p2.tokens[rid] for rid in p1.tokens)
     out["pool_decode_cache"] = p2.server.decode_cache_size()
+    out["pool_prefill_cache"] = p2.server.prefill_cache_size()
     out["pool_upgrades"] = len(p2.server.upgrade_log)
     out["pool_all_enqueue_only"] = all(
         rec["double_buffer"] for rec in p2.server.upgrade_log)
@@ -243,5 +251,7 @@ def test_sharded_moe_speculative_and_pool_token_identity():
     assert out["spec_decode_cache"] == 2
     assert out["pool_tokens_equal"]
     assert out["pool_decode_cache"] == 1
+    assert out["pool_prefill_cache"] == 1, \
+        "the pooled state must start on the mesh: one chunk-step executable"
     assert out["pool_upgrades"] > 0 and out["pool_all_enqueue_only"], \
         "upgrades must stay enqueue-only (zero-stall) on the mesh"

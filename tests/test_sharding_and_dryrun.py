@@ -12,49 +12,54 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.launch.sharding import spec_for_param
 
-# AbstractMesh takes (name, size) pairs on current JAX (the old
-# (sizes, names) two-argument form was removed).
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+
+@pytest.fixture(scope="module")
+def mesh():
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
-def test_spec_matrix_2d():
+@pytest.fixture(scope="module")
+def mesh3():
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+
+
+def test_spec_matrix_2d(mesh):
     # (d_model, d_ff): 21504 % 16 == 0 both dims -> model on larger, fsdp other
-    spec = spec_for_param("decoder/cycles/0_attn/mlp/wi_gate", (62, 5376, 21504), MESH)
+    spec = spec_for_param("decoder/cycles/0_attn/mlp/wi_gate", (62, 5376, 21504), mesh)
     assert spec == P(None, ("data",), "model")
 
 
-def test_spec_scalars_and_vectors_replicated():
-    assert spec_for_param("final_norm/scale", (5376,), MESH) == P()
-    assert spec_for_param("decoder/shared/gate", (), MESH) == P()
+def test_spec_scalars_and_vectors_replicated(mesh):
+    assert spec_for_param("final_norm/scale", (5376,), mesh) == P()
+    assert spec_for_param("decoder/shared/gate", (), mesh) == P()
 
 
-def test_spec_expert_bank_prefers_expert_dim():
+def test_spec_expert_bank_prefers_expert_dim(mesh):
     # dbrx we_gate: (R, E=16, d, f) -> E on model axis (expert parallelism)
-    spec = spec_for_param("decoder/cycles/0_moe/moe/we_gate", (40, 16, 6144, 10752), MESH)
+    spec = spec_for_param("decoder/cycles/0_moe/moe/we_gate", (40, 16, 6144, 10752), mesh)
     assert spec[1] == "model"
     assert "data" in tuple(spec) or ("data",) in tuple(spec)
 
 
-def test_spec_indivisible_expert_dim_falls_back():
+def test_spec_indivisible_expert_dim_falls_back(mesh):
     # mixtral 8 experts on a 16-way model axis -> cannot shard E; a big
     # divisible dim takes model instead
-    spec = spec_for_param("decoder/cycles/0_swa_moe/moe/we_gate", (56, 8, 6144, 16384), MESH)
+    spec = spec_for_param("decoder/cycles/0_swa_moe/moe/we_gate", (56, 8, 6144, 16384), mesh)
     assert spec[1] != "model"
     assert "model" in tuple(spec)
 
 
-def test_spec_multipod_fsdp_includes_pod():
-    spec = spec_for_param("embed", (262144, 5376), MESH3)
+def test_spec_multipod_fsdp_includes_pod(mesh3):
+    spec = spec_for_param("embed", (262144, 5376), mesh3)
     assert spec[0] == "model" or spec[1] == "model"
     flat = tuple(x for x in spec if x is not None)
     assert any(isinstance(x, tuple) and "pod" in x for x in flat)
 
 
-def test_small_tensors_skip_fsdp():
-    spec = spec_for_param("decoder/cycles/0_attn/attn/q_norm_w", (62, 128, 128), MESH)
+def test_small_tensors_skip_fsdp(mesh):
+    spec = spec_for_param("decoder/cycles/0_attn/attn/q_norm_w", (62, 128, 128), mesh)
     # 128*128*62 > threshold -> allowed; but (8, 8): replicated except model
-    spec_small = spec_for_param("x", (8, 8), MESH)
+    spec_small = spec_for_param("x", (8, 8), mesh)
     assert all(s is None for s in spec_small)
 
 
@@ -90,8 +95,7 @@ def test_debug_mesh_dryrun_subprocess(tmp_path):
         with mesh:
             compiled = jax.jit(step, in_shardings=(psh, osh, bsh)).lower(
                 params_sds, opt_sds, batch).compile()
-        from repro.launch.hlo_analysis import normalize_cost_analysis
-        ca = normalize_cost_analysis(compiled.cost_analysis())
+        ca = compiled.cost_analysis()
         # decode too
         caches_sds = jax.eval_shape(lambda: model.init_caches(4, 64))
         csh = sharding.cache_shardings(caches_sds, mesh, batch=4)
@@ -172,20 +176,20 @@ def test_model_flops_moe_counts_active_only():
     assert 0.1 < active / total < 0.35
 
 
-def test_megatron_strategy_directional():
+def test_megatron_strategy_directional(mesh):
     # column-parallel: output dim on model
-    s = spec_for_param("decoder/cycles/0_attn/attn/wq", (16, 2048, 2048), MESH,
+    s = spec_for_param("decoder/cycles/0_attn/attn/wq", (16, 2048, 2048), mesh,
                        "megatron")
     assert s[2] == "model" and s[1] in ("data", ("data",), None)
     # row-parallel: input (contraction) dim on model
-    s = spec_for_param("decoder/cycles/0_attn/attn/wo", (16, 2048, 2048), MESH,
+    s = spec_for_param("decoder/cycles/0_attn/attn/wo", (16, 2048, 2048), mesh,
                        "megatron")
     assert s[1] == "model"
     # non-matching names fall back to greedy
-    g = spec_for_param("embed", (50304, 2048), MESH, "greedy")
-    m = spec_for_param("embed", (50304, 2048), MESH, "megatron")
+    g = spec_for_param("embed", (50304, 2048), mesh, "greedy")
+    m = spec_for_param("embed", (50304, 2048), mesh, "megatron")
     assert g == m
     # expert banks keep expert-parallel override under both strategies
     e = spec_for_param("decoder/cycles/0_moe/moe/we_gate",
-                       (40, 16, 6144, 10752), MESH, "megatron")
+                       (40, 16, 6144, 10752), mesh, "megatron")
     assert e[1] == "model"
