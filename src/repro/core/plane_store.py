@@ -302,6 +302,10 @@ class PlaneStore:
         The whole shipment is validated up front, so a bad item leaves
         the store untouched — callers (e.g. the client's ``_flush``)
         may safely retry the identical shipment after a failure."""
+        with _obs.get_tracer().span("store_ingest", planes=len(items)):
+            self._ingest(items)
+
+    def _ingest(self, items: Sequence[tuple[int, jax.Array]]) -> None:
         pending = list(items)
         counts: dict[int, int] = {}
         for idx, plane in pending:
@@ -348,60 +352,68 @@ class PlaneStore:
         for idx in items:
             dt = np.dtype(self.slots[idx].container).name
             by_dtype.setdefault(dt, []).append(idx)
+        tr = _obs.get_tracer()
         for dt, idxs in by_dtype.items():
             buf = self.buffers[dt]
             idxs.sort(key=lambda i: self.slots[i].offset)
             total = sum(self.slots[i].padded for i in idxs)
             full = total == buf.shape[0]
-            # segment table: one (first block, shift) entry per run of
-            # consecutive tensors sharing a shift — a uniform schedule
-            # collapses a whole stage to a single entry
-            starts: list[int] = []
-            seg_shifts: list[int] = []
-            plane_np = np.zeros((total,), dtype=buf.dtype)
-            pos = 0
-            for idx in idxs:
-                t = self.slots[idx]
-                sh = next_plane_shift(t.schedule, self.received[idx])
-                if not seg_shifts or seg_shifts[-1] != sh:
-                    starts.append(pos // self.block)
-                    seg_shifts.append(sh)
-                # planes are assembled on the host, the DMA landing zone:
-                # one memcpy pass and one upload, whatever the backend
-                plane_np[pos:pos + t.size] = (
-                    np.asarray(items[idx]).reshape(-1))
-                pos += t.padded
-            table = (np.asarray(starts, np.int32),
-                     np.asarray(seg_shifts, np.int32))
-            if self.device is None:
-                seg_starts, shifts = (jnp.asarray(a) for a in table)
-                plane = jnp.asarray(plane_np)
-            else:
-                seg_starts, shifts, plane = jax.device_put(
-                    (*table, plane_np), self.device)
-            if full:
-                # Whole buffer touched (the common full-stage upgrade):
-                # segments are dense by layout, no gather/scatter needed.
-                self.buffers[dt] = ops.plane_or_segments(
-                    buf, plane, seg_starts, shifts, block=self.block)
-            else:
-                # Sparse shipment: sweep only the touched blocks —
-                # O(touched bytes), not O(whole per-dtype buffer).
-                compact = (buf[self.slots[idxs[0]].offset:
-                               self.slots[idxs[0]].offset + total]
-                           if len(idxs) == 1 else
-                           jnp.concatenate([
-                               buf[self.slots[i].offset:
-                                   self.slots[i].offset + self.slots[i].padded]
-                               for i in idxs]))
-                out = ops.plane_or_segments(
-                    compact, plane, seg_starts, shifts, block=self.block)
-                segs, pos = [], 0
+            with tr.span("store_assemble", dtype=dt):
+                # segment table: one (first block, shift) entry per run
+                # of consecutive tensors sharing a shift — a uniform
+                # schedule collapses a whole stage to a single entry
+                starts: list[int] = []
+                seg_shifts: list[int] = []
+                plane_np = np.zeros((total,), dtype=buf.dtype)
+                pos = 0
                 for idx in idxs:
                     t = self.slots[idx]
-                    segs.append((t.offset, pos, t.padded))
+                    sh = next_plane_shift(t.schedule, self.received[idx])
+                    if not seg_shifts or seg_shifts[-1] != sh:
+                        starts.append(pos // self.block)
+                        seg_shifts.append(sh)
+                    # planes are assembled on the host, the DMA landing
+                    # zone: one memcpy pass and one upload, whatever the
+                    # backend
+                    plane_np[pos:pos + t.size] = (
+                        np.asarray(items[idx]).reshape(-1))
                     pos += t.padded
-                self.buffers[dt] = _scatter_segments(buf, out, tuple(segs))
+                table = (np.asarray(starts, np.int32),
+                         np.asarray(seg_shifts, np.int32))
+            with tr.span("store_upload", dtype=dt):
+                if self.device is None:
+                    seg_starts, shifts = (jnp.asarray(a) for a in table)
+                    plane = jnp.asarray(plane_np)
+                else:
+                    seg_starts, shifts, plane = jax.device_put(
+                        (*table, plane_np), self.device)
+            with tr.span("store_or", dtype=dt):
+                if full:
+                    # Whole buffer touched (the common full-stage
+                    # upgrade): segments are dense by layout, no
+                    # gather/scatter needed.
+                    self.buffers[dt] = ops.plane_or_segments(
+                        buf, plane, seg_starts, shifts, block=self.block)
+                else:
+                    # Sparse shipment: sweep only the touched blocks —
+                    # O(touched bytes), not O(whole per-dtype buffer).
+                    compact = (buf[self.slots[idxs[0]].offset:
+                                   self.slots[idxs[0]].offset + total]
+                               if len(idxs) == 1 else
+                               jnp.concatenate([
+                                   buf[self.slots[i].offset:
+                                       self.slots[i].offset
+                                       + self.slots[i].padded]
+                                   for i in idxs]))
+                    out = ops.plane_or_segments(
+                        compact, plane, seg_starts, shifts, block=self.block)
+                    segs, pos = [], 0
+                    for idx in idxs:
+                        t = self.slots[idx]
+                        segs.append((t.offset, pos, t.padded))
+                        pos += t.padded
+                    self.buffers[dt] = _scatter_segments(buf, out,
+                                                         tuple(segs))
         for idx in items:
             self.received[idx] += 1
             self._dirty.add(idx)
@@ -605,30 +617,31 @@ class PlaneStore:
         ``ingest``, only touched keys rebuild — a precision upgrade is
         the ingest plus this metadata refresh, no ``materialize()``."""
         out: dict[Any, Any] = {}
-        for key, idxs in self._by_key().items():
-            if eligible is None or eligible(key):
-                got = self._qleaf_cache.get(key)
-                if got is None:
-                    got = self._quantized_leaf(key, idxs)
+        with _obs.get_tracer().span("store_quantized_leaves"):
+            for key, idxs in self._by_key().items():
+                if eligible is None or eligible(key):
+                    got = self._qleaf_cache.get(key)
+                    if got is None:
+                        got = self._quantized_leaf(key, idxs)
+                        if got is not None:
+                            self._qleaf_cache[key] = got
                     if got is not None:
-                        self._qleaf_cache[key] = got
-                if got is not None:
-                    if bits is not None:
-                        # clamp per leaf: schedules may differ per
-                        # tensor, and bits >= the leaf's own width just
-                        # means "full precision, masked form" — the
-                        # no-op mask keeps the draft and target views
-                        # treedef-identical, so one decode executable
-                        # serves both
-                        b_eff = min(bits, got.bits)
-                        trunc = self._qtrunc_cache.get((key, b_eff))
-                        if trunc is None:
-                            trunc = got.truncate(b_eff)
-                            self._qtrunc_cache[(key, b_eff)] = trunc
-                        got = trunc
-                    out[key] = got
-                    continue
-            out[key] = self._fp_leaf(key, idxs)
+                        if bits is not None:
+                            # clamp per leaf: schedules may differ per
+                            # tensor, and bits >= the leaf's own width
+                            # just means "full precision, masked form" —
+                            # the no-op mask keeps the draft and target
+                            # views treedef-identical, so one decode
+                            # executable serves both
+                            b_eff = min(bits, got.bits)
+                            trunc = self._qtrunc_cache.get((key, b_eff))
+                            if trunc is None:
+                                trunc = got.truncate(b_eff)
+                                self._qtrunc_cache[(key, b_eff)] = trunc
+                            got = trunc
+                        out[key] = got
+                        continue
+                out[key] = self._fp_leaf(key, idxs)
         self._dirty.clear()
         return out
 
@@ -851,6 +864,10 @@ class ShardedPlaneStore:
         sub-store untouched); each sub-store then runs its own batched
         ``plane_or_segments`` rounds on its own device — launches are
         the per-shard sums, and no accumulator bytes cross devices."""
+        with _obs.get_tracer().span("store_ingest", planes=len(items)):
+            self._ingest(items)
+
+    def _ingest(self, items: Sequence[tuple[int, jax.Array]]) -> None:
         pending = list(items)
         counts: dict[int, int] = {}
         for idx, plane in pending:
@@ -890,7 +907,7 @@ class ShardedPlaneStore:
                 sub_items[j].append((lidx, plane))
         for j, its in enumerate(sub_items):
             if its:
-                self.substores[j].ingest(its)
+                self.substores[j]._ingest(its)
         for idx, _ in pending:
             self.received[idx] += 1
             self._g_dirty.add(idx)
@@ -1060,23 +1077,24 @@ class ShardedPlaneStore:
         share those exact global buffers (zero extra weight bytes,
         sharded or not)."""
         out: dict[Any, Any] = {}
-        for key, idxs in self._groups.items():
-            if eligible is None or eligible(key):
-                got = self._g_qleaf_cache.get(key)
-                if got is None:
-                    got = self._quantized_leaf(key)
+        with _obs.get_tracer().span("store_quantized_leaves"):
+            for key, idxs in self._groups.items():
+                if eligible is None or eligible(key):
+                    got = self._g_qleaf_cache.get(key)
+                    if got is None:
+                        got = self._quantized_leaf(key)
+                        if got is not None:
+                            self._g_qleaf_cache[key] = got
                     if got is not None:
-                        self._g_qleaf_cache[key] = got
-                if got is not None:
-                    if bits is not None:
-                        b_eff = min(bits, got.bits)
-                        trunc = self._g_qtrunc_cache.get((key, b_eff))
-                        if trunc is None:
-                            trunc = got.truncate(b_eff)
-                            self._g_qtrunc_cache[(key, b_eff)] = trunc
-                        got = trunc
-                    out[key] = got
-                    continue
-            out[key] = self._fp_leaf(key)
+                        if bits is not None:
+                            b_eff = min(bits, got.bits)
+                            trunc = self._g_qtrunc_cache.get((key, b_eff))
+                            if trunc is None:
+                                trunc = got.truncate(b_eff)
+                                self._g_qtrunc_cache[(key, b_eff)] = trunc
+                            got = trunc
+                        out[key] = got
+                        continue
+                out[key] = self._fp_leaf(key)
         self._g_dirty.clear()
         return out

@@ -33,15 +33,10 @@ def reset_launch_counts() -> None:
 
 
 def _count(name: str) -> None:
-    """Tally one dispatch: the legacy ``LAUNCH_COUNTS`` view plus the
-    telemetry registry (``kernel_launches_total{kernel=...}``) when it
-    is enabled — one source of truth, two readers."""
+    """Tally one call of a public kernel wrapper. Under ``jax.jit`` the
+    wrapper runs once per trace, not once per launch: a profiler trace
+    counts real launches, by kernel name."""
     LAUNCH_COUNTS[name] += 1
-    from repro import obs as _obs
-    if _obs.enabled():
-        _obs.get_registry().counter(
-            "kernel_launches_total",
-            "Pallas kernel dispatches by entry point").inc(kernel=name)
 
 
 def _interpret_default() -> bool:

@@ -17,10 +17,16 @@ is that instrumented hot paths — plane ingest, decode windows, the
 byte-clock session loop — behave *identically* with telemetry off, and
 enabling it only ever observes values the code already computed: no
 device syncs, no extra host transfers, no byte-clock perturbation.
+
+Mutation is thread-safe: a registry's metrics share one lock, taken
+only on the enabled path (the disabled path never reaches a metric), so
+a feeder thread and a serving thread can report at the same time
+without losing a read-modify-write.
 """
 from __future__ import annotations
 
 import math
+import threading
 from typing import Any, Iterable
 
 LabelSet = tuple[tuple[str, str], ...]
@@ -91,10 +97,12 @@ class Metric:
 
     kind = "untyped"
 
-    def __init__(self, name: str, help: str = ""):
+    def __init__(self, name: str, help: str = "",
+                 lock: threading.Lock | None = None):
         self.name = name
         self.help = help
         self._data: dict[LabelSet, Any] = {}
+        self._lock = lock if lock is not None else threading.Lock()
 
     def labelsets(self) -> list[LabelSet]:
         return sorted(self._data)
@@ -110,7 +118,8 @@ class Counter(Metric):
             raise ValueError(f"counter {self.name} cannot decrease "
                              f"(inc {amount})")
         ls = _labelset(labels)
-        self._data[ls] = self._data.get(ls, 0.0) + amount
+        with self._lock:
+            self._data[ls] = self._data.get(ls, 0.0) + amount
 
     def value(self, **labels) -> float:
         return self._data.get(_labelset(labels), 0.0)
@@ -125,11 +134,14 @@ class Gauge(Metric):
     kind = "gauge"
 
     def set(self, value: float, **labels) -> None:
-        self._data[_labelset(labels)] = float(value)
+        ls = _labelset(labels)
+        with self._lock:
+            self._data[ls] = float(value)
 
     def inc(self, amount: float = 1.0, **labels) -> None:
         ls = _labelset(labels)
-        self._data[ls] = self._data.get(ls, 0.0) + amount
+        with self._lock:
+            self._data[ls] = self._data.get(ls, 0.0) + amount
 
     def dec(self, amount: float = 1.0, **labels) -> None:
         self.inc(-amount, **labels)
@@ -149,7 +161,9 @@ class Histogram(Metric):
     kind = "histogram"
 
     def observe(self, value: float, **labels) -> None:
-        self._data.setdefault(_labelset(labels), []).append(float(value))
+        ls = _labelset(labels)
+        with self._lock:
+            self._data.setdefault(ls, []).append(float(value))
 
     def count(self, **labels) -> int:
         return len(self._data.get(_labelset(labels), ()))
@@ -196,16 +210,18 @@ class MetricsRegistry:
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
+        self.lock = threading.Lock()
         self._metrics: dict[str, Metric] = {}
 
     def _get(self, cls, name: str, help: str):
         if not self.enabled:
             return NULL_METRIC
-        got = self._metrics.get(name)
-        if got is None:
-            got = cls(name, help)
-            self._metrics[name] = got
-        elif not isinstance(got, cls):
+        with self.lock:
+            got = self._metrics.get(name)
+            if got is None:
+                got = cls(name, help, self.lock)
+                self._metrics[name] = got
+        if not isinstance(got, cls):
             raise TypeError(
                 f"metric {name!r} already registered as {got.kind}, "
                 f"requested {cls.kind}")
@@ -228,7 +244,8 @@ class MetricsRegistry:
         return [self._metrics[n] for n in sorted(self._metrics)]
 
     def clear(self) -> None:
-        self._metrics.clear()
+        with self.lock:
+            self._metrics.clear()
 
     def __len__(self) -> int:
         return len(self._metrics)

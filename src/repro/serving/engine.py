@@ -300,27 +300,18 @@ class PrecisionManagedEngine:
                 raise RuntimeError(
                     f"receiver has no new stage (at {avail}, "
                     f"served {self._consumed})")
-            self._consumed = avail
-            t1 = time.perf_counter()   # ingest happened externally
-            self._refresh_params()
+            self._consumed = avail     # ingest happened externally
         else:
             s = self.state.received_stages + 1
             self.state = self.state.receive(self.prog.stage(s))
-            t1 = time.perf_counter()
+        t1 = time.perf_counter()
+        with _obs.get_tracer().span("upgrade_refresh", stage=self.stage):
             self._refresh_params()
         # enqueue-time split consumed by upgrade_if_available's log
         self._last_upgrade_split = {
             "ingest_s": t1 - t0,
             "refresh_s": time.perf_counter() - t1,
         }
-        if _obs.enabled():
-            tr = _obs.get_tracer()
-            tr.record("upgrade_ingest",
-                      wall_s=self._last_upgrade_split["ingest_s"],
-                      stage=self.stage)
-            tr.record("upgrade_refresh",
-                      wall_s=self._last_upgrade_split["refresh_s"],
-                      stage=self.stage)
 
 
 class ProgressiveServer(PrecisionManagedEngine):
@@ -813,37 +804,39 @@ class SlotPoolEngine(PrecisionManagedEngine):
         sync."""
         if not self._prefill_state:
             return
-        C, B = self.prefill_chunk, self.n_slots
-        toks = np.zeros((B, C), np.int32)
-        tpos = np.full((B, C), -1, np.int32)
-        frow = np.full((B,), -1, np.int32)
-        done: list[int] = []
-        for slot, st in self._prefill_state.items():
-            off, L = st["off"], st["len"]
-            if off == 0:
-                # the stage the prompt is actually consumed at — an
-                # upgrade may land between submit and the first chunk
-                # tick. (Chunks beyond the first are not re-recorded: a
-                # mid-prefill upgrade makes a single "prefill stage"
-                # ill-defined; parity tests pin the upgrade-free case.)
-                self.admit_stage[st["rid"]] = self.stage
-            n = min(C, L - off)
-            toks[slot, :n] = st["prompt"][off:off + n]
-            tpos[slot, :n] = np.arange(off, off + n, dtype=np.int32)
-            if off + n == L:
-                frow[slot] = n - 1
-                done.append(slot)
-            st["off"] = off + n
-        (self.caches, self.pos, self.last_logits, self._last_tok,
-         self._first_cap) = self._chunk_step(
-            self.params, self.caches, jnp.asarray(toks), jnp.asarray(tpos),
-            jnp.asarray(frow), self.pos, self.last_logits, self._last_tok,
-            self._first_cap)
-        self._tick_count += 1
-        self._win_prefill_ticks += 1
-        for slot in done:
-            del self._prefill_state[slot]
-            self._on_prefill_complete(slot)
+        with _obs.get_tracer().span("engine_prefill_tick",
+                                    rows=len(self._prefill_state)):
+            C, B = self.prefill_chunk, self.n_slots
+            toks = np.zeros((B, C), np.int32)
+            tpos = np.full((B, C), -1, np.int32)
+            frow = np.full((B,), -1, np.int32)
+            done: list[int] = []
+            for slot, st in self._prefill_state.items():
+                off, L = st["off"], st["len"]
+                if off == 0:
+                    # the stage the prompt is actually consumed at — an
+                    # upgrade may land between submit and the first chunk
+                    # tick. (Chunks beyond the first are not re-recorded: a
+                    # mid-prefill upgrade makes a single "prefill stage"
+                    # ill-defined; parity tests pin the upgrade-free case.)
+                    self.admit_stage[st["rid"]] = self.stage
+                n = min(C, L - off)
+                toks[slot, :n] = st["prompt"][off:off + n]
+                tpos[slot, :n] = np.arange(off, off + n, dtype=np.int32)
+                if off + n == L:
+                    frow[slot] = n - 1
+                    done.append(slot)
+                st["off"] = off + n
+            (self.caches, self.pos, self.last_logits, self._last_tok,
+             self._first_cap) = self._chunk_step(
+                self.params, self.caches, jnp.asarray(toks), jnp.asarray(tpos),
+                jnp.asarray(frow), self.pos, self.last_logits, self._last_tok,
+                self._first_cap)
+            self._tick_count += 1
+            self._win_prefill_ticks += 1
+            for slot in done:
+                del self._prefill_state[slot]
+                self._on_prefill_complete(slot)
 
     def _on_prefill_complete(self, slot: int) -> None:
         """Subclass hook when a slot's chunked prefill finishes
@@ -888,26 +881,29 @@ class SlotPoolEngine(PrecisionManagedEngine):
             raise RuntimeError("no planes received yet — call receive_stage()")
         if self._win_t0 is None:
             self._win_t0 = time.perf_counter()
-        self._prefill_tick()
-        snapshot = self.active_rids()
-        if not snapshot:
-            return snapshot
-        nxt = jnp.argmax(self.last_logits, axis=-1).astype(jnp.int32)[:, None]
-        logits, self.caches = self._decode(self.params, self.caches, nxt,
-                                           self.pos)
-        active = jnp.asarray(
-            [i in snapshot for i in range(self.n_slots)], dtype=bool)
-        self.pos = jnp.where(active, self.pos + 1, self.pos)
-        self.last_logits = logits
-        self._pending.append((nxt, snapshot, self.stage))
-        self._step_count += 1
-        # dispatch-time bookkeeping: budgets decrement without reading
-        # token values, so length-complete slots free immediately
-        for slot in snapshot:
-            s = self.slots[slot]
-            s.dispatched += 1
-            if s.dispatched >= s.budget:
-                self._evict(slot)
+        with _obs.get_tracer().span("engine_step"):
+            self._prefill_tick()
+            snapshot = self.active_rids()
+            if not snapshot:
+                return snapshot
+            nxt = jnp.argmax(self.last_logits,
+                             axis=-1).astype(jnp.int32)[:, None]
+            logits, self.caches = self._decode(self.params, self.caches, nxt,
+                                               self.pos)
+            active = jnp.asarray(
+                [i in snapshot for i in range(self.n_slots)], dtype=bool)
+            self.pos = jnp.where(active, self.pos + 1, self.pos)
+            self.last_logits = logits
+            self._pending.append((nxt, snapshot, self.stage))
+            self._step_count += 1
+            # dispatch-time bookkeeping: budgets decrement without
+            # reading token values, so length-complete slots free
+            # immediately
+            for slot in snapshot:
+                s = self.slots[slot]
+                s.dispatched += 1
+                if s.dispatched >= s.budget:
+                    self._evict(slot)
         return snapshot
 
     def flush(self) -> PoolStepStats | None:
@@ -915,38 +911,39 @@ class SlotPoolEngine(PrecisionManagedEngine):
         their requests, complete eos/budget-finished ones."""
         if not self._pending:
             return None
-        jax.block_until_ready(self.last_logits)
-        toks = np.asarray(jnp.concatenate([t for t, _, _ in self._pending],
-                                          axis=1))  # (B, n_pending)
-        wall = time.perf_counter() - (self._win_t0 or time.perf_counter())
-        emitted = 0
-        eos_hit: set[int] = set()
-        for j, (_, snapshot, stage) in enumerate(self._pending):
-            for slot, rid in snapshot.items():
-                if rid in eos_hit:
-                    continue
-                tok = int(toks[slot, j])
-                if not self.outputs[rid]:
-                    self._note_first_token(rid)
-                self.outputs[rid].append(tok)
-                self.stage_log[rid].append(stage)
-                emitted += 1
-                if self.eos_id is not None and tok == self.eos_id:
-                    eos_hit.add(rid)
-                    # the slot may already be freed by budget bookkeeping
-                    if not self.slots[slot].free and \
-                            self.slots[slot].rid == rid:
-                        self._evict(slot)
-        # every retired request's final in-flight tokens just landed;
-        # incremental, so a long-lived pool never rescans its history
-        self.completed |= self._retired
-        self._retired.clear()
-        stats = PoolStepStats(steps=len(self._pending), wall_s=wall,
-                              tokens_emitted=emitted,
-                              upgrades=self._win_upgrades,
-                              upgrade_enqueue_s=self._win_upgrade_enqueue_s,
-                              prefill_ticks=self._win_prefill_ticks)
-        return self._record_window(stats)
+        with _obs.get_tracer().span("engine_flush"):
+            jax.block_until_ready(self.last_logits)
+            toks = np.asarray(jnp.concatenate([t for t, _, _ in self._pending],
+                                              axis=1))  # (B, n_pending)
+            wall = time.perf_counter() - (self._win_t0 or time.perf_counter())
+            emitted = 0
+            eos_hit: set[int] = set()
+            for j, (_, snapshot, stage) in enumerate(self._pending):
+                for slot, rid in snapshot.items():
+                    if rid in eos_hit:
+                        continue
+                    tok = int(toks[slot, j])
+                    if not self.outputs[rid]:
+                        self._note_first_token(rid)
+                    self.outputs[rid].append(tok)
+                    self.stage_log[rid].append(stage)
+                    emitted += 1
+                    if self.eos_id is not None and tok == self.eos_id:
+                        eos_hit.add(rid)
+                        # the slot may already be freed by budget bookkeeping
+                        if not self.slots[slot].free and \
+                                self.slots[slot].rid == rid:
+                            self._evict(slot)
+            # every retired request's final in-flight tokens just landed;
+            # incremental, so a long-lived pool never rescans its history
+            self.completed |= self._retired
+            self._retired.clear()
+            stats = PoolStepStats(
+                steps=len(self._pending), wall_s=wall,
+                tokens_emitted=emitted, upgrades=self._win_upgrades,
+                upgrade_enqueue_s=self._win_upgrade_enqueue_s,
+                prefill_ticks=self._win_prefill_ticks)
+            return self._record_window(stats)
 
     def _record_window(self, stats: PoolStepStats) -> PoolStepStats:
         """Window chokepoint shared with the speculative pool: append
@@ -996,12 +993,15 @@ class SlotPoolEngine(PrecisionManagedEngine):
         if self.stage >= self.prog.n_stages or \
                 self.stages_available <= self.stage:
             return False
-        t0 = time.perf_counter()
-        self.receive_stage()
-        enqueue_s = time.perf_counter() - t0
-        if not self.double_buffer:
-            jax.block_until_ready(jax.tree.leaves(self.params))
-        stall_s = time.perf_counter() - t0
+        with _obs.get_tracer().span("engine_upgrade") as sp:
+            t0 = time.perf_counter()
+            self.receive_stage()
+            enqueue_s = time.perf_counter() - t0
+            if not self.double_buffer:
+                jax.block_until_ready(jax.tree.leaves(self.params))
+            stall_s = time.perf_counter() - t0
+            if sp is not None:
+                sp["stage"] = self.stage
         self.upgrade_enqueue_s += enqueue_s
         self.upgrade_stall_s += stall_s
         self._win_upgrades += 1

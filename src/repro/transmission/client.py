@@ -86,7 +86,8 @@ class ProgressiveClient:
             # the transport is expected to restart the stream from byte
             # 0 (see resume_cursor); accept the fresh bytes
             self.header_failed = False
-        self._buf.extend(chunk)
+        with _obs.get_tracer().span("client_append"):
+            self._buf.extend(chunk)
         self._advance()
         if _obs.enabled():
             reg = _obs.get_registry()
@@ -280,38 +281,55 @@ class ProgressiveClient:
         assert self._layout is not None
         while self._stage < len(self._layout.stages):
             entries = self._layout.stages[self._stage]
-            while self._entry < len(entries):
-                idx, w, nbytes, n_el = entries[self._entry]
-                if len(self._buf) - self._cursor < nbytes:
-                    return
-                payload = bytes(self._buf[self._cursor : self._cursor + nbytes])
-                self._pending.append((idx, wire.decode_plane(
-                    payload, w, n_el, framed=self._layout.framed)))
-                self._cursor += nbytes
-                self._entry += 1
+            with _obs.get_tracer().span("client_decode") as sp:
+                n = self._decode_arrived(entries)
+                if sp is not None:
+                    sp["planes"] = n
+            if self._entry < len(entries):
+                return
             self._stage += 1
             self._entry = 0
             self._flush()
             if self._on_stage_complete:
                 self._on_stage_complete(self._stage)
 
-    # -- v3: verify-before-ingest --------------------------------------------
-    def _advance_v3(self) -> None:
-        while self._next_unit < len(self._units):
-            seq = self._next_unit
-            nbytes = self._units[seq][2]
+    def _decode_arrived(self, entries) -> int:
+        """Decode the current stage's planes whose bytes have all
+        arrived, in order; returns how many."""
+        n = 0
+        while self._entry < len(entries):
+            idx, w, nbytes, n_el = entries[self._entry]
             if len(self._buf) - self._cursor < nbytes:
                 break
-            payload = bytes(self._buf[self._cursor:self._cursor + nbytes])
+            payload = bytes(self._buf[self._cursor : self._cursor + nbytes])
+            self._pending.append((idx, wire.decode_plane(
+                payload, w, n_el, framed=self._layout.framed)))
             self._cursor += nbytes
-            self._next_unit += 1
-            if seq in self._verified:
-                # duplicated bytes on the stream (e.g. an injected
-                # repeat already repaired out of band)
-                self.duplicate_units += 1
-                continue
-            if self._verify_and_stash(seq, payload, origin="stream"):
-                self._nacks.pop(seq, None)
+            self._entry += 1
+            n += 1
+        return n
+
+    # -- v3: verify-before-ingest --------------------------------------------
+    def _advance_v3(self) -> None:
+        with _obs.get_tracer().span("client_decode") as sp:
+            first = self._next_unit
+            while self._next_unit < len(self._units):
+                seq = self._next_unit
+                nbytes = self._units[seq][2]
+                if len(self._buf) - self._cursor < nbytes:
+                    break
+                payload = bytes(self._buf[self._cursor:self._cursor + nbytes])
+                self._cursor += nbytes
+                self._next_unit += 1
+                if seq in self._verified:
+                    # duplicated bytes on the stream (e.g. an injected
+                    # repeat already repaired out of band)
+                    self.duplicate_units += 1
+                    continue
+                if self._verify_and_stash(seq, payload, origin="stream"):
+                    self._nacks.pop(seq, None)
+            if sp is not None:
+                sp["planes"] = self._next_unit - first
         self._advance_contig()
 
     def _verify_and_stash(self, seq: int, payload: bytes,

@@ -196,7 +196,7 @@ def test_tracer_dual_clock_records():
     tr = Tracer(reg)
     wall_only = tr.record("decode_window", wall_s=0.02, engine="pool")
     sim_only = tr.record("stage_arrival", sim_t0=0.0, sim_t1=3.5, stage=2)
-    both = tr.record("upgrade_ingest", wall_s=0.001, sim_t0=1.0, sim_t1=1.25)
+    both = tr.record("upgrade_refresh", wall_s=0.001, sim_t0=1.0, sim_t1=1.25)
     assert wall_only.sim_s is None and "wall_s" in wall_only.to_dict()
     assert sim_only.wall_s is None and sim_only.sim_s == pytest.approx(3.5)
     assert both.to_dict()["sim_s"] == pytest.approx(0.25)
@@ -214,6 +214,97 @@ def test_tracer_inert_when_disabled():
     with tr.span("y"):
         pass
     assert tr.spans == [] and len(reg) == 0
+
+
+def test_tracer_disabled_opens_no_annotation_and_reads_no_clock(monkeypatch):
+    import time
+
+    import jax.profiler
+
+    def boom(*a, **k):
+        raise AssertionError("disabled tracer touched the clock or profiler")
+
+    monkeypatch.setattr(time, "perf_counter", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    tr = Tracer(MetricsRegistry(enabled=False))
+    with tr.span("engine_step", rows=3) as sp:
+        assert sp is None
+    assert tr.spans == []
+
+
+def test_tracer_span_lands_on_profiler_host_plane(tmp_path):
+    """An enabled span is a ``repro:<name>`` annotation on the host
+    plane of a profiler trace, with its labels (including one added
+    inside the block) as stats, on the line of the thread that ran it."""
+    import threading
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer(MetricsRegistry(enabled=True))
+
+    def work(stage):
+        with tr.span("client_decode", stage=stage) as sp:
+            jnp.ones((8, 8)).sum().block_until_ready()
+            sp["planes"] = 10 + stage
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        th = threading.Thread(target=work, args=(2,))
+        th.start()
+        work(1)
+        th.join(timeout=60)
+    finally:
+        jax.profiler.stop_trace()
+    assert not th.is_alive()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name == "repro:client_decode":
+                    st = dict(e.stats)
+                    found[st["stage"]] = (i, st["planes"], e.duration_ns)
+    assert set(found) == {1, 2}
+    assert found[1][1] == 11 and found[2][1] == 12
+    assert found[1][0] != found[2][0]           # one line per thread
+    assert all(d > 0 for _, _, d in found.values())
+    recs = tr.of("client_decode")
+    assert sorted(r.labels["planes"] for r in recs) == [11, 12]
+    assert all(r.wall_s > 0 for r in recs)
+
+
+def test_registry_and_tracer_lose_no_update_across_threads():
+    import sys
+    import threading
+
+    reg = MetricsRegistry(enabled=True)
+    tr = Tracer(reg)
+    n_threads, n = 16, 400
+
+    def work():
+        for _ in range(n):
+            reg.counter("hits_total").inc()
+            reg.gauge("level").inc()
+            tr.record("tick", wall_s=0.0)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ths = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in ths:
+            t.start()
+        for t in ths:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ths)
+    total = n_threads * n
+    assert reg.counter("hits_total").value() == total
+    assert reg.gauge("level").value() == total
+    assert len(tr.of("tick")) == total
+    assert reg.get("span_tick_wall_s").count() == total
 
 
 def test_global_telemetry_context_restores_and_clears():

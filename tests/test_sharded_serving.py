@@ -139,7 +139,11 @@ def test_sharded_dense_serving_token_identity_and_ingest():
     out = {}
     r1 = serve(None, "fp")
     ops.reset_launch_counts()
-    r2 = serve(mesh, "fp")
+    from repro import obs
+    with obs.telemetry(True):
+        r2 = serve(mesh, "fp")
+        out["store_ingest_spans"] = len(obs.get_tracer().of("store_ingest"))
+        out["store_or_spans"] = len(obs.get_tracer().of("store_or"))
     out["fp_tokens_equal"] = bool(np.array_equal(
         np.asarray(r1.tokens), np.asarray(r2.tokens)))
     out["stages_equal"] = r1.stage_at_step == r2.stage_at_step
@@ -148,6 +152,7 @@ def test_sharded_dense_serving_token_identity_and_ingest():
     n_active = sum(1 for sub in store.substores if sub.n_tensors > 0)
     out["ingest_launches"] = ops.LAUNCH_COUNTS["plane_or_segments"]
     out["expected_launches"] = prog.n_stages * n_active
+    out["n_stages"] = prog.n_stages
     out["plane_or"] = ops.LAUNCH_COUNTS["plane_or"]
     out["fp_decode_cache"] = r2.server.decode_cache_size()
     r3 = serve(mesh, "quantized")
@@ -172,6 +177,10 @@ def test_sharded_dense_serving_token_identity_and_ingest():
     assert out["ingest_launches"] == out["expected_launches"], \
         "shard-local ingest: one batched launch per sub-store per stage"
     assert out["plane_or"] == 0
+    # one store_ingest span per shipment (the sub-stores' rounds nest
+    # inside it, one store_or each)
+    assert out["store_ingest_spans"] == out["n_stages"]
+    assert out["store_or_spans"] == out["expected_launches"]
     assert out["fp_decode_cache"] == 1 and out["quant_decode_cache"] == 1
     assert out["dqm_identical"]
 

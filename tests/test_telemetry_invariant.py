@@ -149,10 +149,10 @@ def test_enabled_run_mirrors_log_into_registry(served):
             reg.get("session_stage_completions_total").value(stage=s)
             for s in range(1, prog.n_stages + 1))
         assert n_stages == len(res.events_of("stage_complete"))
-        # kernel launches bridged from ops.LAUNCH_COUNTS
-        k = reg.get("kernel_launches_total")
-        assert k is not None and \
-            k.value(kernel="plane_or_segments") >= prog.n_stages
+        # one store ingest per completed stage, timed where it happens
+        ingests = obs.get_tracer().of("store_ingest")
+        assert len(ingests) == len(res.events_of("stage_complete"))
+        assert all(s.wall_s is not None for s in ingests)
         # dual-clock spans: stage arrivals live on the sim clock
         arrivals = obs.get_tracer().of("stage_arrival")
         assert len(arrivals) == len(res.events_of("stage_complete"))
@@ -161,6 +161,15 @@ def test_enabled_run_mirrors_log_into_registry(served):
         # engine decode windows live on the wall clock
         windows = obs.get_tracer().of("decode_window")
         assert windows and all(s.wall_s is not None for s in windows)
+        # the slot pool times each step it dispatches
+        pool = Session(blob, BandwidthTrace.constant(100e3),
+                       chunk_bytes=4096).run_serving_pool(
+            model, prog, prompts=[batch["tokens"][0]], max_new_tokens=3,
+            n_slots=2, dispatch_window=2)
+        steps = obs.get_tracer().of("engine_step")
+        assert steps and all(s.wall_s is not None for s in steps)
+        assert reg.get("span_engine_step_wall_s").count() == len(steps)
+        assert pool.tokens
 
 
 def test_seq_is_monotonic_and_serialized(served):
