@@ -12,7 +12,7 @@ pod-coldstart)`` into ``ProgressiveClient -> PlaneStore -> SlotPoolEngine``
 (``run_serving_pool``: quantized residency, chunked prefill, 4 slots,
 4 prompts of 128 tokens, 16 new tokens each) and must reach stage 8.
 Then every Pallas kernel is checked against its ``kernels/ref.py`` oracle
-at olmo-1b shapes.
+at olmo-1b shapes (the plane unpack also at every width it takes).
 
 Four chips. The same stream is served twice in one process: by the
 one-chip pool and by the pool on ``make_serving_mesh(4)``. Tokens must be
@@ -277,6 +277,38 @@ def check_kernels(smoke: Smoke, cfg, blob: bytes) -> None:
     same = bool(equal_to_oracle(out, acc, plane))
     smoke.check(f"plane_or_segments n={n} segments={starts.shape[0]}", same,
                 "bit-identical to the oracle" if same else "differs")
+    del out, acc, plane
+
+    # plane unpack: every width into uint16 on a small buffer, and the
+    # 2-bit stage of the whole olmo-1b buffer as the store uploads it
+    for width, m in ((1, 1 << 20), (2, 1 << 20), (4, 1 << 20), (8, 1 << 20),
+                     (2, n)):
+        nbytes = m * width // 8
+        packed = jax.jit(lambda k: jax.random.bits(k, (nbytes,), jnp.uint8)
+                         )(next(key))
+        out = ops.plane_unpack(packed, width=width, dtype=jnp.uint16)
+        chunk = next(nbytes // c for c in range(1, nbytes + 1)
+                     if nbytes % c == 0 and nbytes // c <= 1 << 16)
+
+        @jax.jit
+        def unpack_equal(out, packed):
+            # chunked: the oracle's (bytes, 8 / width) array pads to 128
+            # lanes on the chip
+            vals = chunk * 8 // width
+
+            def body(i, ok):
+                want = ref.plane_unpack_ref(jax.lax.dynamic_slice_in_dim(
+                    packed, i * chunk, chunk), width, jnp.uint16)
+                got = jax.lax.dynamic_slice_in_dim(out, i * vals, vals)
+                return ok & jnp.array_equal(got, want)
+
+            return jax.lax.fori_loop(0, nbytes // chunk, body,
+                                     jnp.bool_(True))
+
+        same = bool(unpack_equal(out, packed))
+        smoke.check(f"plane_unpack width={width} n={m} -> uint16", same,
+                    "bit-identical to the oracle" if same else "differs")
+        del out, packed
 
 
 def main() -> None:

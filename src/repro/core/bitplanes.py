@@ -119,7 +119,9 @@ PAPER_DEFAULT = PlaneSchedule(bits=16, widths=(2,) * 8)
 # increase" true on the wire. Packing is host work in NumPy: wire bytes
 # live in host memory at both ends, and the group layout below — an
 # (n, values-per-group) array — would be padded to (n, 128) lanes by a
-# TPU's tiled layout.
+# TPU's tiled layout. The receiver's unpack of a whole stage runs on
+# the device instead (``kernels/bitplane.plane_unpack``, lane-dense);
+# ``unpack_bits`` is its oracle and the host fallback.
 # ---------------------------------------------------------------------------
 
 def _bit_group(width: int) -> tuple[int, int]:
@@ -211,3 +213,27 @@ def unpack_bits(packed, width: int, n_elements: int) -> np.ndarray:
             out[:, i] |= _field(bys[:, b], hi_bit - o_hi, nbits, vdt,
                                 v_hi - o_hi)
     return out.reshape(-1)[:n_elements].astype(np.uint32, copy=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class PackedPlane:
+    """One plane as the wire carries it: ``n_elements`` values of
+    ``width`` bits, packed by :func:`pack_bits` into ``data``. The
+    PlaneStore takes these as they are and unpacks them on the device
+    where it can (``kernels/bitplane.plane_unpack``), on the host with
+    :meth:`unpack` where it cannot."""
+
+    data: bytes
+    width: int
+    n_elements: int
+
+    def __post_init__(self):
+        need = -(-self.n_elements * self.width // 8)
+        if len(self.data) != need:
+            raise ValueError(
+                f"packed plane has {len(self.data)} bytes, need {need} for "
+                f"{self.n_elements} width-{self.width} values")
+
+    def unpack(self) -> np.ndarray:
+        return unpack_bits(np.frombuffer(self.data, np.uint8), self.width,
+                           self.n_elements)

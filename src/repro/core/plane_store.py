@@ -22,7 +22,9 @@ issues ONE batched
 ``plane_or_segments`` Pallas launch per container dtype — O(1) in the
 number of tensors, vs. the old one-``pallas_call``-per-tensor loop.
 Block alignment is what makes the per-block shift well defined: a block
-never straddles two tensors.
+never straddles two tensors. Planes may arrive as the wire's packed
+bytes (:class:`~repro.core.bitplanes.PackedPlane`); the store then
+uploads those and unpacks them on the device (``plane_unpack``).
 
 Materialization (eq. 5)
 -----------------------
@@ -44,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs as _obs
-from repro.core.bitplanes import PlaneSchedule
+from repro.core.bitplanes import PackedPlane, PlaneSchedule
 from repro.core.quantize import (QuantizedTensor, affine_span,
                                  container_dtype, dequant_affine,
                                  dequant_constants, dequantize_buffers)
@@ -67,6 +69,32 @@ def _scatter_segments(buf: jax.Array, out: jax.Array,
         buf = jax.lax.dynamic_update_slice_in_dim(
             buf, jax.lax.dynamic_slice_in_dim(out, pos, length), off, axis=0)
     return buf
+
+
+def _plane_size(plane) -> int:
+    if isinstance(plane, PackedPlane):
+        return plane.n_elements
+    return int(np.prod(np.shape(plane)) or 1)
+
+
+def _count_unpacked(n: int, where: str) -> None:
+    if n and _obs.enabled():
+        _obs.get_registry().counter(
+            "store_planes_unpacked_total",
+            "packed planes unpacked by the store").inc(n, where=where)
+
+
+def _device_width(planes: Sequence) -> int | None:
+    """The width the device can unpack a dtype's share of a round at:
+    every plane packed, all at one width dividing 8 (so each tensor's
+    bytes start on a byte boundary of the packed staging buffer)."""
+    widths = {p.width if isinstance(p, PackedPlane) else None
+              for p in planes}
+    if len(widths) == 1:
+        (w,) = widths
+        if w is not None and 8 % w == 0:
+            return w
+    return None
 
 
 def next_plane_shift(schedule: PlaneSchedule, received: int) -> int:
@@ -290,9 +318,10 @@ class PlaneStore:
                 for dt, buf in sorted(self.buffers.items())}
 
     # -- eq. (4): batched upgrade -----------------------------------------
-    def ingest(self, items: Sequence[tuple[int, jax.Array]]) -> None:
+    def ingest(self, items: Sequence[tuple[int, Any]]) -> None:
         """OR a shipment of planes into the store. ``items`` holds
-        ``(tensor_idx, plane_values)`` pairs; each plane is the *next*
+        ``(tensor_idx, plane)`` pairs, the plane given as its values or
+        as the wire's :class:`PackedPlane`; each plane is the *next*
         plane of its tensor's schedule (the wire delivers them in
         order). One ``plane_or_segments`` launch per container dtype per
         round; a shipment carrying several planes of the same tensor is
@@ -305,12 +334,12 @@ class PlaneStore:
         with _obs.get_tracer().span("store_ingest", planes=len(items)):
             self._ingest(items)
 
-    def _ingest(self, items: Sequence[tuple[int, jax.Array]]) -> None:
+    def _ingest(self, items: Sequence[tuple[int, Any]]) -> None:
         pending = list(items)
         counts: dict[int, int] = {}
         for idx, plane in pending:
             t = self.slots[idx]
-            n = int(np.prod(np.shape(plane)) or 1)
+            n = _plane_size(plane)
             if n != t.size:
                 raise ValueError(
                     f"plane for tensor {idx} has {n} elements, "
@@ -323,7 +352,7 @@ class PlaneStore:
                     f"tensor {idx}: {have} planes received + {c} arriving "
                     f"exceeds schedule of {total}")
         while pending:
-            round_items: dict[int, jax.Array] = {}
+            round_items: dict[int, Any] = {}
             rest = []
             for idx, plane in pending:
                 if idx in round_items:
@@ -333,7 +362,7 @@ class PlaneStore:
             self._ingest_round(round_items)
             pending = rest
 
-    def _ingest_round(self, items: dict[int, jax.Array]) -> None:
+    def _ingest_round(self, items: dict[int, Any]) -> None:
         """One OR round: the accumulator never round-trips through the
         host. Touched segments are gathered into a *compact* buffer
         (cheap XLA slices/concat, no kernel launches), the single
@@ -341,7 +370,15 @@ class PlaneStore:
         results go back via one fused scatter — a sparse shipment's OR
         work and transfers are O(touched bytes); the write-back is a
         single whole-buffer update (immutable arrays), not one per
-        segment."""
+        segment.
+
+        Where a dtype's planes all arrived packed at one width ``w``
+        dividing 8, the host lays their bytes out like the blocks (a
+        tensor at element ``pos`` starts at byte ``pos * w / 8``),
+        uploads ``w / 8`` bytes per element and ``plane_unpack`` expands
+        them on the device. Otherwise (mixed or odd widths, value
+        arrays) the plane is assembled from values on the host, packed
+        planes unpacked there."""
         if _obs.enabled():
             reg = _obs.get_registry()
             reg.counter("store_or_rounds_total",
@@ -358,13 +395,19 @@ class PlaneStore:
             idxs.sort(key=lambda i: self.slots[i].offset)
             total = sum(self.slots[i].padded for i in idxs)
             full = total == buf.shape[0]
+            w = _device_width([items[i] for i in idxs])
             with tr.span("store_assemble", dtype=dt):
                 # segment table: one (first block, shift) entry per run
                 # of consecutive tensors sharing a shift — a uniform
                 # schedule collapses a whole stage to a single entry
                 starts: list[int] = []
                 seg_shifts: list[int] = []
-                plane_np = np.zeros((total,), dtype=buf.dtype)
+                # the staging buffer is the DMA landing zone: one memcpy
+                # pass and one upload, whatever the backend
+                if w is None:
+                    staging = np.zeros((total,), buf.dtype)
+                else:
+                    staging = np.zeros((total * w // 8,), np.uint8)
                 pos = 0
                 for idx in idxs:
                     t = self.slots[idx]
@@ -372,21 +415,29 @@ class PlaneStore:
                     if not seg_shifts or seg_shifts[-1] != sh:
                         starts.append(pos // self.block)
                         seg_shifts.append(sh)
-                    # planes are assembled on the host, the DMA landing
-                    # zone: one memcpy pass and one upload, whatever the
-                    # backend
-                    plane_np[pos:pos + t.size] = (
-                        np.asarray(items[idx]).reshape(-1))
+                    p = items[idx]
+                    if w is not None:
+                        self._stage_packed(staging, pos * w // 8, p)
+                    else:
+                        if isinstance(p, PackedPlane):
+                            p = p.unpack()
+                        staging[pos:pos + t.size] = np.asarray(p).reshape(-1)
                     pos += t.padded
                 table = (np.asarray(starts, np.int32),
                          np.asarray(seg_shifts, np.int32))
+            _count_unpacked(
+                sum(isinstance(items[i], PackedPlane) for i in idxs),
+                "host" if w is None else "device")
             with tr.span("store_upload", dtype=dt):
                 if self.device is None:
                     seg_starts, shifts = (jnp.asarray(a) for a in table)
-                    plane = jnp.asarray(plane_np)
+                    plane = jnp.asarray(staging)
                 else:
                     seg_starts, shifts, plane = jax.device_put(
-                        (*table, plane_np), self.device)
+                        (*table, staging), self.device)
+            if w is not None:
+                with tr.span("store_unpack", dtype=dt):
+                    plane = ops.plane_unpack(plane, width=w, dtype=buf.dtype)
             with tr.span("store_or", dtype=dt):
                 if full:
                     # Whole buffer touched (the common full-stage
@@ -414,6 +465,7 @@ class PlaneStore:
                         pos += t.padded
                     self.buffers[dt] = _scatter_segments(buf, out,
                                                          tuple(segs))
+            del plane
         for idx in items:
             self.received[idx] += 1
             self._dirty.add(idx)
@@ -423,6 +475,19 @@ class PlaneStore:
             self._qleaf_cache.pop(key, None)
             for tk in [t for t in self._qtrunc_cache if t[0] == key]:
                 self._qtrunc_cache.pop(tk)
+
+    @staticmethod
+    def _stage_packed(staging: np.ndarray, at: int,
+                      plane: PackedPlane) -> None:
+        """Copy a packed plane's bytes to ``staging[at:]``, with the
+        unused low bits of a ragged last byte cleared so the padding
+        elements after the tensor unpack to 0."""
+        data = np.frombuffer(plane.data, np.uint8)
+        end = at + data.size
+        staging[at:end] = data
+        tail = plane.n_elements * plane.width % 8
+        if tail:
+            staging[end - 1] &= (0xFF << (8 - tail)) & 0xFF
 
     # -- eq. (5): incremental materialization ------------------------------
     def _by_key(self) -> dict[Any, list[int]]:
@@ -858,21 +923,24 @@ class ShardedPlaneStore:
                                bits=t0.bits, orig_dtype=t0.orig_dtype)
 
     # -- eq. (4): shard-local batched upgrade ------------------------------
-    def ingest(self, items: Sequence[tuple[int, jax.Array]]) -> None:
+    def ingest(self, items: Sequence[tuple[int, Any]]) -> None:
         """Route a shipment to the owning shards and OR it there.
         Validation is global and up front (a bad item leaves every
         sub-store untouched); each sub-store then runs its own batched
         ``plane_or_segments`` rounds on its own device — launches are
-        the per-shard sums, and no accumulator bytes cross devices."""
+        the per-shard sums, and no accumulator bytes cross devices.
+        Packed planes are unpacked on the host first: a split tensor's
+        rows are routed by value, and a shard's piece of a packed plane
+        need not start on a byte."""
         with _obs.get_tracer().span("store_ingest", planes=len(items)):
             self._ingest(items)
 
-    def _ingest(self, items: Sequence[tuple[int, jax.Array]]) -> None:
+    def _ingest(self, items: Sequence[tuple[int, Any]]) -> None:
         pending = list(items)
         counts: dict[int, int] = {}
         for idx, plane in pending:
             size = int(np.prod(self.shapes[idx]) or 1)
-            n = int(np.prod(np.shape(plane)) or 1)
+            n = _plane_size(plane)
             if n != size:
                 raise ValueError(
                     f"plane for tensor {idx} has {n} elements, "
@@ -884,6 +952,10 @@ class ShardedPlaneStore:
                 raise ValueError(
                     f"tensor {idx}: {have} planes received + {c} arriving "
                     f"exceeds schedule of {total}")
+        _count_unpacked(sum(isinstance(p, PackedPlane) for _, p in pending),
+                        "host")
+        pending = [(i, p.unpack() if isinstance(p, PackedPlane) else p)
+                   for i, p in pending]
         sub_items: list[list[tuple[int, Any]]] = [
             [] for _ in range(self._n_model)]
         for idx, plane in pending:

@@ -30,10 +30,11 @@ invariant ``PlaneStore.ingest`` enforces) but interleave freely *across*
 tensors — see :mod:`repro.core.calibrate`. Each unit body is either the
 raw packed plane (``MODE_RAW``) or its entropy-coded form
 (:mod:`repro.core.entropy`), chosen per-plane so a coded unit is never
-larger than raw + the 2-byte frame. ``decode_plane`` undoes the framing
-before ``unpack_bits``, so everything downstream of the client —
-PlaneStore ingest, OR-reassembly, the eq.-(5) affine — is untouched and
-the fully-received model is bit-identical to the v1 stream's.
+larger than raw + the 2-byte frame. ``packed_plane`` undoes the framing
+and hands on the same packed bytes a v1 stream carries, so everything
+downstream of the client — PlaneStore ingest, OR-reassembly, the eq.-(5)
+affine — is untouched and the fully-received model is bit-identical to
+the v1 stream's.
 
 v3 layout (``encode(model, integrity=True)``) is the fault-tolerant
 wire: the same unit stream as v2, but every unit is preceded by an
@@ -259,7 +260,7 @@ def frame_unit(seq: int, unit: bytes) -> bytes:
 
 def verify_unit(payload: bytes) -> tuple[int, bytes]:
     """Check a v3 unit's integrity frame. Returns ``(seq, body)`` where
-    ``body`` is the v2-framed unit (feed it to ``decode_plane(...,
+    ``body`` is the v2-framed unit (feed it to ``packed_plane(...,
     framed=True)``). Raises :class:`WireIntegrityError` on CRC mismatch
     and :class:`WireFormatError` on truncation."""
     if len(payload) < FRAME_BYTES_V3:
@@ -348,7 +349,7 @@ class StageLayout:
     v1: one stage per plane rank, entries dense-packed. v2
     (``framed=True``): "stages" are checkpoint groups of schedule
     units; each entry's ``payload_bytes`` INCLUDES the 2-byte frame,
-    and payloads must pass through :func:`decode_plane` with
+    and payloads must pass through :func:`packed_plane` with
     ``framed=True`` to strip the frame / undo entropy coding."""
 
     header_bytes: int
@@ -356,7 +357,7 @@ class StageLayout:
     stages: list[list[tuple[int, int, int, int]]]
     framed: bool = False
     # v3: payloads additionally carry the <seq u32><crc u32> integrity
-    # frame and MUST pass wire.verify_unit before decode_plane
+    # frame and MUST pass wire.verify_unit before packed_plane
     integrity: bool = False
 
     def unit_offsets(self) -> list[int]:
@@ -424,14 +425,15 @@ def _layout_v2(meta: dict, header_bytes: int,
                        framed=True, integrity=integrity)
 
 
-def decode_plane(payload: bytes, width: int, n_elements: int,
-                 *, framed: bool = False) -> np.ndarray:
-    """Unpack one plane payload. ``framed=True`` (v2/v3 body) strips
-    the 2-byte mode frame and undoes entropy coding first; the
-    recovered packed bytes are identical to the raw path, so
-    reconstruction downstream is bit-exact either way. Malformed input
-    raises :class:`WireFormatError` with length context. v3 callers
-    strip/verify the integrity frame via :func:`verify_unit` first."""
+def packed_plane(payload: bytes, width: int, n_elements: int,
+                 *, framed: bool = False) -> bitplanes.PackedPlane:
+    """One plane payload as packed bytes, checked but not unpacked.
+    ``framed=True`` (v2/v3 body) strips the 2-byte mode frame and undoes
+    entropy coding first; the recovered packed bytes are identical to
+    the raw path, so reconstruction downstream is bit-exact either way.
+    Malformed input raises :class:`WireFormatError` with length
+    context. v3 callers strip/verify the integrity frame via
+    :func:`verify_unit` first."""
     raw_len = -(-n_elements * width // 8)
     if framed:
         if len(payload) < FRAME_BYTES:
@@ -440,7 +442,8 @@ def decode_plane(payload: bytes, width: int, n_elements: int,
                 f"frame: {len(payload)} bytes")
         mode = payload[0]
         try:
-            payload = entropy.decode(mode, payload[FRAME_BYTES:], raw_len)
+            payload = entropy.decode(mode, memoryview(payload)[FRAME_BYTES:],
+                                     raw_len)
         except Exception as e:
             raise WireFormatError(
                 f"undecodable unit body (mode {mode}, "
@@ -450,5 +453,10 @@ def decode_plane(payload: bytes, width: int, n_elements: int,
         raise WireFormatError(
             f"plane payload is {len(payload)} bytes, expected {raw_len} "
             f"({n_elements} elements x {width} bits)")
-    return bitplanes.unpack_bits(np.frombuffer(payload, dtype=np.uint8),
-                                 width, n_elements)
+    return bitplanes.PackedPlane(payload, width, n_elements)
+
+
+def decode_plane(payload: bytes, width: int, n_elements: int,
+                 *, framed: bool = False) -> np.ndarray:
+    """:func:`packed_plane`, unpacked on the host into uint32 values."""
+    return packed_plane(payload, width, n_elements, framed=framed).unpack()

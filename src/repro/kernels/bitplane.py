@@ -13,6 +13,11 @@ decode steps; it never blocks the MXU for long.
 
 The same kernel also implements eq. (3) extraction (split) via shift
 masks, so divide/concat are one code path.
+
+``plane_unpack`` expands a stage's packed wire bytes into the plane
+the OR reads, so the host uploads ``w`` bits per element instead of a
+container element (2-bit planes into uint16: 8x fewer bytes) and never
+unpacks a value.
 """
 from __future__ import annotations
 
@@ -20,6 +25,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -158,6 +164,110 @@ def plane_or_segments(acc: jax.Array, plane: jax.Array, seg_starts: jax.Array,
         out_shape=jax.ShapeDtypeStruct(a2.shape, acc.dtype),
         interpret=interpret,
     )(seg_starts.astype(jnp.int32), seg_shifts.astype(jnp.int32), a2, p2)
+    return out.reshape(-1)
+
+
+# Input rows per step of plane_unpack's inner loop: one uint8 tile.
+_UNPACK_SUB = 32
+
+
+def _unpack_consts(width: int) -> tuple[np.ndarray, np.ndarray]:
+    """The two 0/1 matrices of :func:`plane_unpack` for ``v = 8 //
+    width`` values per byte. ``expand`` (128, 128 v) copies byte
+    ``(128 / v) k + l // v`` of a row to column ``128 k + l``: column
+    block k holds the bytes of output row ``v j + k``, each repeated
+    once per value it carries. ``interleave`` (32 v, 32 v) moves row j
+    of column block k, stacked as row ``32 k + j``, to output row
+    ``v j + k``. Each output column has a single 1, so the products are
+    exact in f32."""
+    v = 8 // width
+    b = np.arange(128)[:, None]
+    c = np.arange(128 * v)[None, :]
+    expand = b == (128 // v) * (c // 128) + (c % 128) // v
+    r = np.arange(_UNPACK_SUB * v)
+    interleave = np.zeros((r.size, r.size), bool)
+    interleave[r, (r % v) * _UNPACK_SUB + r // v] = True
+    return expand, interleave
+
+
+def _unpack_kernel(x_ref, *refs, width: int):
+    o_ref = refs[-1]
+    v = 8 // width
+    if v == 1:
+        o_ref[...] = x_ref[...].astype(jnp.int32).astype(o_ref.dtype)
+        return
+    e_ref, s_ref = refs[:2]
+    rows = _UNPACK_SUB * v
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+    shift = 8 - width * (1 + lane % v)   # big-endian: value 0 on top
+
+    def body(i, carry):
+        # bytes 0..255 are exact in bf16; Mosaic has no unsigned ->
+        # float cast, so widen to int32 first
+        x = x_ref[pl.ds(pl.multiple_of(i * _UNPACK_SUB, _UNPACK_SUB),
+                        _UNPACK_SUB), :]
+        x = x.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+        y = jnp.dot(x, e_ref[...], preferred_element_type=jnp.float32)
+        y = jnp.concatenate([y[:, k * 128:(k + 1) * 128] for k in range(v)],
+                            axis=0).astype(jnp.bfloat16)
+        z = jnp.dot(s_ref[...], y, preferred_element_type=jnp.float32)
+        q = (z.astype(jnp.int32) >> shift) & ((1 << width) - 1)
+        o_ref[pl.ds(pl.multiple_of(i * rows, rows), rows), :] = (
+            q.astype(jnp.uint32).astype(o_ref.dtype))
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0] // _UNPACK_SUB, body, 0)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("width", "dtype", "block_rows",
+                                    "interpret"))
+def plane_unpack(packed: jax.Array, *, width: int, dtype,
+                 block_rows: int = 2048,
+                 interpret: bool = False) -> jax.Array:
+    """Expand a flat buffer of packed ``width``-bit values (big-endian
+    within each byte, as :func:`repro.core.bitplanes.pack_bits` writes
+    them) into one ``dtype`` element per value, on the device.
+
+    ``packed`` is uint8 of length ``n * width / 8`` with ``n`` a
+    multiple of 128; the result has ``n`` elements, so a zero byte
+    gives zero elements (the PlaneStore's padding). ``width`` divides
+    8. Lane-dense throughout: a (32, 128) byte tile becomes the
+    (32 v, 128) output rows it covers, ``v = 8 / width``, by two 0/1
+    matmuls on the MXU (``_unpack_consts``: expand bytes along lanes,
+    then interleave rows), a per-lane shift and a mask. No
+    (n, v)-shaped array is ever formed: a TPU pads a minor dimension of
+    v to 128 lanes. Each grid step writes ``block_rows`` rows of 128
+    elements; the last step may be partial."""
+    if 8 % width:
+        raise ValueError(f"width must divide 8, got {width}")
+    if packed.ndim != 1 or packed.dtype != jnp.uint8:
+        raise ValueError(
+            f"plane_unpack wants flat uint8 bytes, got {packed.dtype} "
+            f"{packed.shape}")
+    v = 8 // width
+    n = packed.shape[0] * v
+    if n % 128:
+        raise ValueError(f"{n} values is not a multiple of 128")
+    if block_rows % (_UNPACK_SUB * v):
+        raise ValueError(
+            f"block_rows {block_rows} not a multiple of {_UNPACK_SUB * v}")
+    x = packed.reshape(-1, 128)
+    in_rows = block_rows // v
+    operands = [x]
+    in_specs = [pl.BlockSpec((in_rows, 128), lambda i: (i, 0))]
+    if v > 1:
+        for c in _unpack_consts(width):
+            operands.append(jnp.asarray(c, jnp.bfloat16))
+            in_specs.append(pl.BlockSpec(c.shape, lambda i: (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_unpack_kernel, width=width),
+        grid=(pl.cdiv(n // 128, block_rows),),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // 128, 128), dtype),
+        interpret=interpret,
+    )(*operands)
     return out.reshape(-1)
 
 

@@ -98,6 +98,12 @@ def plane_or_segments(acc, plane, seg_starts, seg_shifts, **kw):
     return _bp.plane_or_segments(acc, plane, seg_starts, seg_shifts, **kw)
 
 
+def plane_unpack(packed, *, width, dtype, **kw):
+    _count("plane_unpack")
+    kw.setdefault("interpret", _interpret_default())
+    return _bp.plane_unpack(packed, width=width, dtype=dtype, **kw)
+
+
 def plane_extract(q, *, bits, before, width, **kw):
     _count("plane_extract")
     kw.setdefault("interpret", _interpret_default())

@@ -38,6 +38,16 @@ def plane_or_ref(acc: jax.Array, plane: jax.Array, shift: int) -> jax.Array:
     return (acc.astype(jnp.uint32) | (plane.astype(jnp.uint32) << shift)).astype(acc.dtype)
 
 
+def plane_unpack_ref(packed: jax.Array, width: int, dtype) -> jax.Array:
+    """Packed ``width``-bit values (big-endian within each byte) -> one
+    ``dtype`` element per value. Forms the (bytes, 8 / width) array the
+    kernel avoids."""
+    v = 8 // width
+    shifts = 8 - width * (1 + jnp.arange(v, dtype=jnp.uint8))
+    vals = (packed[:, None] >> shifts) & jnp.uint8((1 << width) - 1)
+    return vals.reshape(-1).astype(dtype)
+
+
 def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                      k_pos: jax.Array, q_pos: jax.Array,
                      *, window: int = 0, softcap: float = 0.0) -> jax.Array:

@@ -2,11 +2,13 @@
 
 Consumes the wire format produced by :mod:`repro.core.wire` incrementally
 (arbitrary chunk boundaries — a transport delivers bytes, not planes).
-Decoded planes are fed straight into a shared
-:class:`~repro.core.plane_store.PlaneStore`: completed planes are
-buffered and flushed as one *batched* OR launch per stage completion
-(eq. 4), and ``materialize()`` is the store's incremental eq. (5) —
-tensors untouched since the last call are served from cache.
+Completed planes are checked and handed, still packed
+(:class:`~repro.core.bitplanes.PackedPlane`), to a shared
+:class:`~repro.core.plane_store.PlaneStore`: they are buffered and
+flushed as one *batched* OR launch per stage completion (eq. 4), the
+store unpacking them on the device, and ``materialize()`` is the
+store's incremental eq. (5) — tensors untouched since the last call
+are served from cache.
 
 Fault tolerance (wire v3)
 -------------------------
@@ -15,7 +17,7 @@ accumulator for the rest of the session. On a v3 (integrity-framed)
 stream the client therefore *verifies before it ingests*:
 
 * every unit's CRC32 + sequence number is checked the moment its bytes
-  are complete — BEFORE any decode or ``plane_or_segments`` launch;
+  are complete — BEFORE its body is decoded or reaches the store;
 * a unit that fails verification is **quarantined**: its bytes are
   consumed (lengths come from the header, so stream sync survives) but
   nothing reaches the store, and a NACK entry is recorded for the
@@ -40,10 +42,9 @@ from __future__ import annotations
 import struct
 from typing import Callable
 
-import numpy as np
-
 from repro import obs as _obs
 from repro.core import wire
+from repro.core.bitplanes import PackedPlane
 from repro.core.plane_store import PlaneStore
 
 
@@ -61,7 +62,7 @@ class ProgressiveClient:
         self._meta = None
         self._layout: wire.StageLayout | None = None
         self.store: PlaneStore | None = None
-        self._pending: list[tuple[int, np.ndarray]] = []  # decoded, un-OR-ed
+        self._pending: list[tuple[int, PackedPlane]] = []  # un-OR-ed
         self._cursor = 0          # absolute offset of next undecoded byte
         self._stage = 0           # completed stages
         self._entry = 0           # next entry within current stage
@@ -72,7 +73,7 @@ class ProgressiveClient:
         self._unit_offsets: list[int] = []
         self._checkpoints: list[int] = []
         self._next_unit = 0             # stream position, in units
-        self._ready: dict[int, tuple[int, np.ndarray]] = {}  # seq -> (t, plane)
+        self._ready: dict[int, tuple[int, PackedPlane]] = {}  # seq -> (t, plane)
         self._verified: set[int] = set()
         self._nacks: dict[int, str] = {}          # seq -> quarantine reason
         self._contig = 0                # all seq < _contig verified
@@ -274,9 +275,16 @@ class ProgressiveClient:
         self.quarantine_log.append({"seq": None, "target": "header",
                                     "reason": reason})
 
+    def _payload(self, nbytes: int) -> bytes:
+        """The ``nbytes`` at the cursor, copied out of the buffer once.
+        The memoryview is a temporary: a live export of ``_buf`` would
+        make the next ``feed``'s extend raise ``BufferError``."""
+        a = self._cursor
+        return bytes(memoryview(self._buf)[a:a + nbytes])
+
     # -- v1/v2: trusted in-order stream -------------------------------------
     def _advance_stream(self) -> None:
-        # Decode completed planes; the eq. (4) OR happens in batched
+        # Queue completed planes; the eq. (4) OR happens in batched
         # flushes, not per plane.
         assert self._layout is not None
         while self._stage < len(self._layout.stages):
@@ -294,16 +302,15 @@ class ProgressiveClient:
                 self._on_stage_complete(self._stage)
 
     def _decode_arrived(self, entries) -> int:
-        """Decode the current stage's planes whose bytes have all
-        arrived, in order; returns how many."""
+        """Check the current stage's planes whose bytes have all
+        arrived, in order, and queue them packed; returns how many."""
         n = 0
         while self._entry < len(entries):
             idx, w, nbytes, n_el = entries[self._entry]
             if len(self._buf) - self._cursor < nbytes:
                 break
-            payload = bytes(self._buf[self._cursor : self._cursor + nbytes])
-            self._pending.append((idx, wire.decode_plane(
-                payload, w, n_el, framed=self._layout.framed)))
+            self._pending.append((idx, wire.packed_plane(
+                self._payload(nbytes), w, n_el, framed=self._layout.framed)))
             self._cursor += nbytes
             self._entry += 1
             n += 1
@@ -318,7 +325,7 @@ class ProgressiveClient:
                 nbytes = self._units[seq][2]
                 if len(self._buf) - self._cursor < nbytes:
                     break
-                payload = bytes(self._buf[self._cursor:self._cursor + nbytes])
+                payload = self._payload(nbytes)
                 self._cursor += nbytes
                 self._next_unit += 1
                 if seq in self._verified:
@@ -334,11 +341,11 @@ class ProgressiveClient:
 
     def _verify_and_stash(self, seq: int, payload: bytes,
                           origin: str) -> bool:
-        """CRC/seq-check one on-wire unit; decode and stage it for
-        in-order ingest on success, quarantine on failure. Decode
-        errors after a *passing* CRC (possible only for malformed
-        repair lengths) quarantine too — nothing unverified can reach
-        the store."""
+        """CRC/seq-check one on-wire unit; decode its body to packed
+        bytes and stage it for in-order ingest on success, quarantine on
+        failure. Decode errors after a *passing* CRC (possible only for
+        malformed repair lengths) quarantine too — nothing unverified
+        can reach the store."""
         idx, w, nbytes, n_el = self._units[seq]
         reason = None
         try:
@@ -353,7 +360,7 @@ class ProgressiveClient:
             reason = str(e)
         if reason is None:
             try:
-                plane = wire.decode_plane(body, w, n_el, framed=True)
+                plane = wire.packed_plane(body, w, n_el, framed=True)
             except wire.WireFormatError as e:
                 reason = f"verified frame but undecodable body: {e}"
         if reason is not None:

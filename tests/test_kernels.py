@@ -122,6 +122,45 @@ def test_plane_or_matches_ref(bits):
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
+# layouts as the PlaneStore stages them: tensors of these element counts
+# packed back to back, each padded to ``block`` elements; block_rows
+# (rows of 128 elements per grid step), so one layout spans several
+# grid steps with a partial last one
+UNPACK_LAYOUTS = {
+    "ragged-multi-tensor": ((1000, 1, 2051, 77), 1024, 2048),
+    "multi-step-partial": ((70_000, 9), 1024, 256),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(UNPACK_LAYOUTS))
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.uint16, jnp.uint32])
+@pytest.mark.parametrize("width", [1, 2, 4, 8])
+def test_plane_unpack_matches_unpack_bits(width, dtype, layout):
+    """plane_unpack (interpret mode) and its jnp oracle both give
+    unpack_bits' values, and 0 in every padding element, whatever the
+    tensors' counts (none of them need be a multiple of 8 / width)."""
+    from repro.kernels.bitplane import plane_unpack
+
+    sizes, block, block_rows = UNPACK_LAYOUTS[layout]
+    rng = np.random.default_rng(width * 100 + len(sizes))
+    padded = [-(-n // block) * block for n in sizes]
+    packed = np.zeros(sum(padded) * width // 8, np.uint8)
+    want = np.zeros(sum(padded), np.uint32)
+    pos = 0
+    for n, span in zip(sizes, padded):
+        vals = rng.integers(0, 2 ** width, n)
+        pk = bitplanes.pack_bits(vals, width)
+        packed[pos * width // 8:pos * width // 8 + pk.size] = pk
+        want[pos:pos + n] = bitplanes.unpack_bits(pk, width, n)
+        pos += span
+    got = plane_unpack(jnp.asarray(packed), width=width, dtype=dtype,
+                       block_rows=block_rows, interpret=True)
+    oracle = ref.plane_unpack_ref(jnp.asarray(packed), width, dtype)
+    for out in (got, oracle):
+        assert out.dtype == dtype and out.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(out), want.astype(dtype))
+
+
 # ---------------------------------------------------------------------------
 # flash decode attention (ragged batches, native (B, Kh, S, hd) layout)
 # ---------------------------------------------------------------------------

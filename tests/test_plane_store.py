@@ -231,6 +231,167 @@ def test_from_wire_meta_matches_from_model(params):
                                       np.asarray(leaf))
 
 
+# ---------------------------------------------------------------------------
+# packed planes: the client hands the wire's bytes over, the store
+# unpacks them on the device where one width covers a dtype's round
+# ---------------------------------------------------------------------------
+
+class MixedWidthPolicy(DivisionPolicy):
+    """Both uint16: matrices in 2-bit planes, vectors and scalars in
+    4-bit ones, so each uint16 round mixes two widths."""
+
+    def plan(self, path, shape, dtype, slice_idx=None):
+        widths = (2,) * 8 if len(shape) >= 2 else (4,) * 4
+        return TensorPlan(schedule=PlaneSchedule(bits=16, widths=widths))
+
+    @property
+    def n_stages(self):
+        return 8
+
+
+class OddWidthPolicy(DivisionPolicy):
+    """9-bit codes (uint16 container) in three 3-bit planes: 3 does not
+    divide 8, so a tensor's bytes need not start on a byte."""
+
+    def plan(self, path, shape, dtype, slice_idx=None):
+        return TensorPlan(schedule=PlaneSchedule(bits=9, widths=(3, 3, 3)))
+
+    @property
+    def n_stages(self):
+        return 3
+
+
+def _value_fingerprints(blob: bytes) -> list[dict]:
+    """Fingerprint after each stage of a store fed the stream's planes
+    as values, unpacked on the host (the path packed ingest replaces)."""
+    from repro.core import wire
+
+    meta, hdr = wire.decode_header(blob)
+    layout = wire.layout_from_header(meta, hdr)
+    store = PlaneStore.from_wire_meta(meta)
+    off, fps = hdr, []
+    for stage in layout.stages:
+        items = []
+        for idx, w, nbytes, n_el in stage:
+            payload = blob[off:off + nbytes]
+            off += nbytes
+            if layout.integrity:
+                _, payload = wire.verify_unit(payload)
+            items.append((idx, wire.decode_plane(payload, w, n_el,
+                                                 framed=layout.framed)))
+        store.ingest(items)
+        fps.append(store.fingerprint())
+    return fps
+
+
+def _feed_in_odd_chunks(blob: bytes, mesh=None):
+    """A client fed ``blob`` in chunks of 1, 7, 4093, 13 and 65537
+    bytes in turn; returns it with its fingerprint at each stage and
+    the store_planes_unpacked_total counts by ``where``."""
+    from repro import obs
+    from repro.transmission.client import ProgressiveClient
+
+    fps = []
+    client = ProgressiveClient(
+        on_stage_complete=lambda s: fps.append(client.store.fingerprint()),
+        mesh=mesh)
+    sizes, off, i = (1, 7, 4093, 13, 65537), 0, 0
+    with obs.telemetry(True) as reg:
+        while off < len(blob):
+            client.feed(blob[off:off + sizes[i % len(sizes)]])
+            off += sizes[i % len(sizes)]
+            i += 1
+        c = reg.counter("store_planes_unpacked_total")
+        counts = {w: c.value(where=w) for w in ("device", "host")}
+    return client, fps, counts
+
+
+@pytest.mark.parametrize("version", ["v1", "v2-entropy", "v3"])
+def test_packed_ingest_fingerprint_matches_value_ingest(params, version):
+    """At every stage the store a client fills from the wire's packed
+    bytes (unpacked on the device) is bit-identical to one fed the same
+    planes as host-unpacked values; every plane is counted once, on
+    the device. Mixed containers: uint8 (widths 2, 2, 4) and uint16."""
+    from repro.core import wire
+
+    model = divide(params, MixedBitsPolicy())
+    blob = wire.encode(model, entropy_coded=version == "v2-entropy",
+                       integrity=version == "v3")
+    ops.reset_launch_counts()
+    client, fps, counts = _feed_in_odd_chunks(blob)
+    assert client.complete
+    assert fps == _value_fingerprints(blob)
+    n_planes = sum(t.plan.schedule.n_planes for t in model.tensors)
+    assert counts == {"device": n_planes, "host": 0}
+    assert ops.LAUNCH_COUNTS["plane_unpack"] > 0
+
+
+@pytest.mark.parametrize("policy", [MixedWidthPolicy(), OddWidthPolicy()],
+                         ids=["mixed-widths", "odd-width"])
+def test_packed_ingest_host_path_for_mixed_or_odd_widths(params, policy):
+    """A round whose planes of one dtype differ in width, or whose width
+    does not divide 8, is unpacked on the host: same fingerprints, and
+    each plane counted once, under where="host" in such a round. (With
+    mixed widths only stages 1-4 mix; stages 5-8 carry 2-bit planes
+    alone and go to the device.)"""
+    from repro.core import wire
+
+    blob = wire.encode(divide(params, policy))
+    client, fps, counts = _feed_in_odd_chunks(blob)
+    assert client.complete
+    assert fps == _value_fingerprints(blob)
+    want = {"device": 0, "host": 0}
+    for stage in wire.layout_from_header(*wire.decode_header(blob)).stages:
+        ws = {w for _, w, _, _ in stage}          # all in uint16
+        one = len(ws) == 1 and 8 % min(ws) == 0
+        want["device" if one else "host"] += len(stage)
+    assert want["host"] > 0
+    assert counts == want
+
+
+def test_sharded_store_unpacks_packed_planes_on_host(params):
+    """ShardedPlaneStore routes planes by value rows, so a meshed client
+    unpacks on the host: every plane counted where="host", and each
+    tensor's accumulator equals the single-device client's."""
+    from repro.core import wire
+    from repro.launch.mesh import make_serving_mesh
+
+    blob = wire.encode(divide(params))
+    ops.reset_launch_counts()
+    sharded, _, counts = _feed_in_odd_chunks(blob, mesh=make_serving_mesh(1))
+    assert ops.LAUNCH_COUNTS["plane_unpack"] == 0
+    n_planes = sum(len(t["widths"])
+                   for t in wire.decode_header(blob)[0]["tensors"])
+    assert counts == {"device": 0, "host": n_planes}
+    single, _, _ = _feed_in_odd_chunks(blob)
+    for i in range(single.store.n_tensors):
+        np.testing.assert_array_equal(np.asarray(sharded.store.acc(i)),
+                                      np.asarray(single.store.acc(i)))
+
+
+def test_packed_plane_tail_bits_never_reach_padding():
+    """Set bits past a ragged tensor's last value (which pack_bits
+    never writes) are cleared on staging: the padding stays 0, so the
+    fingerprint matches the value path's."""
+    sched = PlaneSchedule(bits=16, widths=(2,) * 8)
+    entries = [{"key": k, "schedule": sched, "lo": jnp.float32(-1),
+                "hi": jnp.float32(1), "shape": (n,),
+                "orig_dtype": np.float32} for k, n in (("a", 5), ("b", 11))]
+    packed_store = PlaneStore._from_entries(entries)
+    value_store = PlaneStore._from_entries(entries)
+    rng = np.random.default_rng(0)
+    packed, values = [], []
+    for i, n in enumerate((5, 11)):
+        vals = rng.integers(0, 4, n)
+        pk = pack_bits(vals, 2)
+        pk[-1] |= 0xFF >> (n * 2 % 8)     # dirty the unused low bits
+        packed.append((i, bitplanes.PackedPlane(pk.tobytes(), 2, n)))
+        values.append((i, vals))
+    packed_store.ingest(packed)
+    value_store.ingest(values)
+    assert packed_store.fingerprint() == value_store.fingerprint()
+
+
 def test_next_plane_shift_exhaustion():
     sched = PlaneSchedule(bits=16, widths=(2,) * 8)
     assert next_plane_shift(sched, 0) == 14

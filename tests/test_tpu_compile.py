@@ -123,3 +123,21 @@ def test_plane_or_segments_compiles_at_whole_olmo_buffer(
         shape_on_chip((len(leaves),), jnp.int32),
         shape_on_chip((len(leaves),), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_plane_unpack_compiles_at_whole_olmo_buffer(
+        shape_on_chip, no_persistent_cache):
+    """The device unpack of a whole olmo-1b stage: 2-bit planes packed
+    into uint8 bytes in, the uint16 plane the OR reads out."""
+    from repro.core.plane_store import DEFAULT_BLOCK
+
+    leaves = jax.tree.leaves(jax.eval_shape(
+        build_model(OLMO).init, jax.random.PRNGKey(0)))
+    n = sum(-(-int(np.prod(x.shape)) // DEFAULT_BLOCK) * DEFAULT_BLOCK
+            for x in leaves)
+    assert n > 1_176_000_000
+    hlo = _compile_for_chip(
+        lambda p: bitplane.plane_unpack(p, width=2, dtype=jnp.uint16,
+                                        interpret=False),
+        shape_on_chip((n * 2 // 8,), jnp.uint8))
+    assert "tpu_custom_call" in hlo

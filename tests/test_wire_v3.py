@@ -168,6 +168,37 @@ def test_seq_mismatch_is_detected_even_with_valid_crc(setup):
     assert 0 in c.nacks and "sequence mismatch" in c.nacks[0]
 
 
+@pytest.mark.parametrize("damage", ["crc", "body"])
+def test_corrupt_unit_is_quarantined_before_ingest(setup, damage):
+    """The client hands the store packed bytes, and still only verified,
+    decodable ones: a unit whose CRC fails ("crc"), or whose frame is
+    valid around an undecodable body ("body": an unknown entropy mode),
+    is quarantined and nothing reaches the store; its repair then
+    completes the stream bit-identically to the clean one."""
+    from repro.core.plane_store import PlaneStore
+
+    _, _, blob, meta, _, layout = setup
+    seq = 0
+    o, n = layout.unit_offsets()[seq], layout.stages[0][0][2]
+    unit = bytearray(blob[o:o + n])
+    if damage == "crc":
+        unit[-1] ^= 0x01
+        reason = "CRC mismatch"
+    else:  # <seq><crc> | <mode><reserved> | payload
+        unit = wire.frame_unit(seq, b"\xee" + bytes(unit[9:]))
+        reason = "undecodable body"
+    assert len(unit) == n
+    c = ProgressiveClient()
+    c.feed(blob[:o] + bytes(unit) + blob[o + n:])
+    assert seq in c.nacks and reason in c.nacks[seq]
+    assert c.stages_complete == 0 and not any(c.store.received)
+    assert c.store.fingerprint() == \
+        PlaneStore.from_wire_meta(meta).fingerprint()
+    assert c.feed_repair(seq, blob[o:o + n]) and c.complete
+    for a, b in zip(c.materialize().values(), _materialized(blob).values()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # -- typed errors on malformed input --------------------------------------------
 
 def test_decode_header_error_catalogue(setup):
