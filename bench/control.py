@@ -5,8 +5,9 @@ puts first at each position of the same prompts and served tokens.
     python bench/control.py --workload <name> --seed <n> --seconds <s>
 
 Prints the run's result line: ``correct`` is false when the check
-separates the control, ``checks.logit_gap`` is the control's reading and
-``checks.program_logit_gap`` the program's. The limits in
+separates the control, ``checks.logit_gap_<group>`` is the control's
+reading and ``checks.program_logit_gap_<group>`` the program's, for each
+group of stages served (``correct.py``). The limits in
 ``limits/<workload>.json`` are set from these readings (PERF.md gives
 them); the benchmark's own runs never compute the control.
 """

@@ -7,24 +7,29 @@ weights made again from the seed:
   engine served, the per-tensor checksum of the client's accumulators
   taken when the stage was applied, against the reference's codes
   (eq. 2) truncated to that stage. Exact: the limit is 0.
-- ``logit_gap``: for a sample drawn from the seed of the requests that
-  were served tokens (finished, or still decoding when the window
-  closed: every token the client was handed), the longest always in it,
-  the widest gap by which a served token's
+- ``logit_gap_partial`` and ``logit_gap_full``: for a sample drawn from
+  the seed of the requests that were served tokens (finished, or still
+  decoding when the window closed: every token the client was handed),
+  the longest always in it, the widest gap by which a served token's
   logit lies below the reference's best, the reference reading the
   prompt and the served tokens once, each position at the stage the
   server held when it computed it (the engine's ``admit_stage`` for the
   prompt, ``stage_log`` for each decoded position). A request qualifies
   when its whole prompt was consumed at one stage: its admission stage
   is the stage of its first decode step (the stage of each prompt chunk
-  is not observable otherwise).
+  is not observable otherwise). Each served token is judged by the stage
+  of the position it was computed at: ``full`` at the last stage (every
+  plane received), ``partial`` at any earlier one, each against a limit
+  of its own, since the gaps of both the program and the control are
+  wider on coarser weights (PERF.md section 2). A group with no served
+  token is not reported.
 - ``uncompared``: 1 when no served token could be compared.
 
 With ``control`` the fp8 reference (``reference.forward(low=True)``) takes
 the program's place: at each position of the same prompts and served
-tokens, the token it puts first is judged as ``logit_gap`` against the
-same limit, so ``correct`` comes out false; the program's own reading is
-kept beside it as ``program_logit_gap``.
+tokens, the token it puts first is judged as ``logit_gap_<group>``
+against the same limit, so ``correct`` comes out false; the program's
+own reading is kept beside it as ``program_logit_gap_<group>``.
 """
 from __future__ import annotations
 
@@ -79,7 +84,9 @@ def check(cfg, mix, limits, seed, served, admit, stage_log, *,
 
     rids = sample(cfg, mix, seed, served, admit, stage_log, prompts)
     gap_fn = reference.make_gap_fn(cfg, with_control=control)
-    worst, worst_low, n_tok = 0.0, 0.0, 0
+    last = len(cfg["plane_widths"])
+    groups = {g: {"program": 0.0, "control": 0.0, "tokens": 0, "requests": 0, "stages": set()}
+              for g in ("partial", "full")}
     with jax.default_matmul_precision("highest"):
         for rid in rids:
             seq, tgt = reference.served_positions(prompts[rid], served[rid], mix["max_len"])
@@ -89,19 +96,31 @@ def check(cfg, mix, limits, seed, served, admit, stage_log, *,
             ms = np.array([stage_bits(cfg, int(s)) for s in stages], np.int32)
             res = [np.asarray(a) for a in gap_fn(raw, lohi, ms, stage_of.astype(np.int32),
                                                  seq, tgt)]
-            worst = max(worst, float(res[0].max()))
-            if control:
-                worst_low = max(worst_low, float(res[1].max()))
-            n_tok += len(served[rid])
-    served_stages = sorted({s for r in rids for s in stage_log[r]})
+            for g, at in (("partial", st < last), ("full", st == last)):
+                sel = at & (tgt >= 0)
+                if not sel.any():
+                    continue
+                grp = groups[g]
+                grp["program"] = max(grp["program"], float(res[0][sel].max()))
+                if control:
+                    grp["control"] = max(grp["control"], float(res[1][sel].max()))
+                grp["tokens"] += int(sel.sum())
+                grp["requests"] += 1
+                grp["stages"].update(int(s) for s in np.unique(st[sel]))
     across = sum(len(set(stage_log[r])) > 1 for r in rids)
-    log(f"  compared {len(rids)} requests, {n_tok} served tokens, stages "
-        f"{served_stages}, {across} across an upgrade")
-    gap = {"value": worst, "limit": limits["logit_gap"], "requests": len(rids),
-           "tokens": n_tok, "across_upgrade": across}
-    if control:
-        out["program_logit_gap"] = {"value": worst}
-        gap["value"], gap["control"] = worst_low, "fp8"
-    out["logit_gap"] = gap
+    log(f"  compared {len(rids)} requests, {across} across an upgrade; " + "; ".join(
+        f"{g}: {grp['tokens']} served tokens at stages {sorted(grp['stages'])}"
+        for g, grp in groups.items()))
+    for g, grp in groups.items():
+        if not grp["tokens"]:
+            continue
+        gap = {"value": grp["program"], "limit": limits[f"logit_gap_{g}"],
+               "stages": sorted(grp["stages"]), "requests": grp["requests"],
+               "tokens": grp["tokens"]}
+        if control:
+            out[f"program_logit_gap_{g}"] = {"value": grp["program"]}
+            gap["value"], gap["control"] = grp["control"], "fp8"
+        out[f"logit_gap_{g}"] = gap
+    n_tok = sum(grp["tokens"] for grp in groups.values())
     out["uncompared"] = {"value": int(n_tok == 0), "limit": 0}
     return out

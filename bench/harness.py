@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import logging
 import sys
@@ -29,6 +28,7 @@ REPO = ROOT.parent
 if str(REPO / "src") not in sys.path:
     sys.path.insert(0, str(REPO / "src"))
 
+from bench import arch, load_file  # noqa: E402
 from bench import traffic as traffic_mod  # noqa: E402
 
 MIB = 1 << 20
@@ -66,11 +66,7 @@ def metric_names(bench: dict, workload: str, trace: bool) -> list[dict]:
 
 
 def load_reader(name: str):
-    path = ROOT / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(ROOT / "metrics" / f"{name}.py", "bench_metric_").read
 
 
 def load_peaks(kind: str) -> dict:
@@ -88,9 +84,7 @@ def arch_config(cfg: dict):
     from repro.configs import get_config
 
     base = get_config(cfg["program_config"])
-    fields = {k: cfg[k] for k in ("n_layers", "d_model", "n_heads", "n_kv",
-                                  "d_ff", "vocab", "head_dim", "norm_type",
-                                  "act", "rope_theta", "tie_embeddings")}
+    fields = dict(arch.load(cfg).arch_fields(cfg))
     fields["dtype"] = getattr(jnp, cfg["dtype"])
     fields["param_dtype"] = getattr(jnp, cfg["param_dtype"])
     return dataclasses.replace(base, **fields)

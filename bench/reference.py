@@ -1,4 +1,5 @@
-"""Plain float32 reference: the paper's quantizer and the dense decoder block.
+"""Plain float32 reference: the paper's quantizer, and the selection of
+each position's stage that every architecture's decoder shares.
 
 Nothing here imports the program. The reference follows the papers'
 equations and the configuration file, in ``jax.numpy`` at float32 with
@@ -10,10 +11,8 @@ every matrix product at ``Precision.HIGHEST``:
 - eqs. (3)-(4): after ``m`` received bits the receiver holds the top
   ``m`` bits of ``q``;
 - eq. (5): ``w = span * q / 2^bits + lo + span / 2^(m + 1)``;
-- the decoder: token embedding (times sqrt(d_model)), then per layer a
-  pre-norm attention block (grouped-query, rotary positions on the two
-  halves of each head, causal softmax) and a pre-norm gated MLP, a final
-  norm and the tied unembedding.
+- the decoder: the configuration's architecture file under ``arch/``
+  (``forward``), which reads its weights through ``Stages``.
 
 Departures from the published models are the configuration's, listed in
 each file under ``configs/`` and in PERF.md; the reference computes what
@@ -21,18 +20,14 @@ the configuration states.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from bench import arch
+
 HI = lax.Precision.HIGHEST
-PRE = "decoder/cycles/0_attn/"
-LAYER_LEAVES = ("attn/wq", "attn/wk", "attn/wv", "attn/wo",
-                "mlp/wi_gate", "mlp/wi_up", "mlp/wo",
-                "norm1/scale", "norm2/scale")
 
 
 # -- eqs. (2)-(5) ------------------------------------------------------------
@@ -81,34 +76,7 @@ def checksum(q: jax.Array) -> jax.Array:
                       jnp.sum(q, dtype=jnp.uint32)])
 
 
-# -- the decoder ---------------------------------------------------------------
-
-def _norm(cfg, x, scale):
-    if cfg["norm_type"] == "rmsnorm":
-        return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + 1e-5)
-
-
-def _act(cfg, x):
-    if cfg["act"] == "silu":
-        return x / (1.0 + jnp.exp(-x))
-    if cfg["act"] == "gelu":  # the tanh approximation
-        return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                         * (x + 0.044715 * x ** 3)))
-    raise ValueError(cfg["act"])
-
-
-def _rope(x, pos, theta):
-    """x: (T, n, hd). Rotates the pair (i, i + hd/2) by pos * theta^(-2i/hd)."""
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * freqs          # (T, half)
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
-
+# -- what every decoder shares ------------------------------------------------
 
 def _fp8(x):
     """Round to float8 e4m3 with a per-tensor scale (amax -> 448)."""
@@ -123,80 +91,53 @@ def _mm(a, b, low: bool):
     return jnp.dot(a, b, precision=HI)
 
 
-def forward(cfg: dict, raw: dict, lohi: dict, m, tokens, *, low: bool = False,
-            stage_of=None):
-    """Logits (T, V) of one sequence.
-
-    ``raw`` maps leaf paths to the weights as made from the seed;
-    ``lohi`` maps them to their (min, max). Each layer's weights are
-    quantized, truncated and dequantized inside the layer loop, so only
-    one layer's float copies exist at a time. ``low`` computes every
-    matrix product from fp8-rounded operands: the control.
+class Stages:
+    """Each position's own stage, for one sequence of ``T`` positions:
+    the selection every architecture file's ``forward`` shares.
 
     ``m`` is the number of received bits, or an (S,) array of them with
     ``stage_of`` a (T,) index into it: position ``t`` (its key, value,
     activations and logits) is computed at ``m[stage_of[t]]`` bits, as the
-    server computed each position at the stage it held then."""
-    bits = cfg["bits"]
-    T = tokens.shape[0]
-    H, K, hd = cfg["n_heads"], cfg["n_kv"], cfg["head_dim"]
-    G = H // K
-    pos = jnp.arange(T, dtype=jnp.int32)
-    ms = jnp.atleast_1d(jnp.asarray(m, jnp.int32))
-    if stage_of is None:
-        stage_of = jnp.zeros((T,), jnp.int32)
-    at = [stage_of[:, None] == i for i in range(ms.shape[0])]
+    server computed each position at the stage it held then. ``lohi``
+    maps leaf paths to their (min, max); ``low`` computes every matrix
+    product from fp8-rounded operands: the control."""
 
-    def per_stage(f):
-        """f(bits) at each position's own stage."""
-        out = f(ms[0])
-        for i in range(1, len(at)):
-            out = jnp.where(at[i], f(ms[i]), out)
+    def __init__(self, cfg: dict, lohi: dict, m, T: int, stage_of=None, low: bool = False):
+        self.bits, self.lohi, self.low = cfg["bits"], lohi, low
+        self.ms = jnp.atleast_1d(jnp.asarray(m, jnp.int32))
+        if stage_of is None:
+            stage_of = jnp.zeros((T,), jnp.int32)
+        self.at = [stage_of[:, None] == i for i in range(self.ms.shape[0])]
+
+    def per_stage(self, f):
+        """``f(bits)`` at each position's own stage."""
+        out = f(self.ms[0])
+        for i in range(1, len(self.at)):
+            out = jnp.where(self.at[i], f(self.ms[i]), out)
         return out
 
-    def w(name, x, mb):
-        lo, hi = lohi[name]
-        return stage_weight(x, lo, hi, bits, mb)
+    def w(self, name: str, x, mb):
+        """Leaf ``name`` (made from the seed as ``x``) as served after
+        ``mb`` received bits."""
+        lo, hi = self.lohi[name]
+        return stage_weight(x, lo, hi, self.bits, mb)
 
-    def mm(a, name, x):
-        return per_stage(lambda mb: _mm(a, w(name, x, mb), low))
+    def mm(self, a, name: str, x):
+        """``a`` times leaf ``name``, at each position's own stage."""
+        return self.per_stage(lambda mb: self.dot(a, self.w(name, x, mb)))
 
-    x = per_stage(lambda mb: w("embed", raw["embed"], mb)[tokens])
-    x = x * jnp.float32(math.sqrt(cfg["d_model"]))
-    names = [n for n in LAYER_LEAVES if PRE + n in raw]
-    xs = {n: raw[PRE + n] for n in names}
+    def dot(self, a, b):
+        """``a @ b`` at HIGHEST precision, from fp8-rounded operands under ``low``."""
+        return _mm(a, b, self.low)
 
-    def norm(x, p, key):
-        if key not in p:
-            return _norm(cfg, x, None)
-        return per_stage(lambda mb: _norm(cfg, x, w(PRE + key, p[key], mb)))
 
-    def layer(x, p):
-        h = norm(x, p, "norm1/scale")
-        q = mm(h, PRE + "attn/wq", p["attn/wq"]).reshape(T, H, hd)
-        k = mm(h, PRE + "attn/wk", p["attn/wk"]).reshape(T, K, hd)
-        v = mm(h, PRE + "attn/wv", p["attn/wv"]).reshape(T, K, hd)
-        q = _rope(q, pos, cfg["rope_theta"])
-        k = _rope(k, pos, cfg["rope_theta"])
-        k = jnp.repeat(k, G, axis=1)                     # head h reads kv h // G
-        v = jnp.repeat(v, G, axis=1)
-        s = jnp.einsum("thd,shd->hts", q, k, precision=HI) / math.sqrt(hd)
-        s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
-        a = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("hts,shd->thd", a, v, precision=HI).reshape(T, H * hd)
-        x = x + mm(o, PRE + "attn/wo", p["attn/wo"])
-        h = norm(x, p, "norm2/scale")
-        g = (_act(cfg, mm(h, PRE + "mlp/wi_gate", p["mlp/wi_gate"]))
-             * mm(h, PRE + "mlp/wi_up", p["mlp/wi_up"]))
-        return x + mm(g, PRE + "mlp/wo", p["mlp/wo"]), None
-
-    x, _ = lax.scan(layer, x, xs)
-    fn = raw.get("final_norm/scale")
-    if fn is None:
-        x = _norm(cfg, x, None)
-    else:
-        x = per_stage(lambda mb: _norm(cfg, x, w("final_norm/scale", fn, mb)))
-    return per_stage(lambda mb: _mm(x, w("embed", raw["embed"], mb).T, low))
+def forward(cfg: dict, raw: dict, lohi: dict, m, tokens, *, low: bool = False,
+            stage_of=None):
+    """Logits (T, V) of one sequence, by the configuration's architecture
+    file (``bench/arch``): ``raw`` maps leaf paths to the weights as made
+    from the seed, ``lohi`` to their (min, max); ``m``, ``stage_of`` and
+    ``low`` as ``Stages`` takes them."""
+    return arch.load(cfg).forward(cfg, raw, lohi, m, tokens, low=low, stage_of=stage_of)
 
 
 def make_gap_fn(cfg: dict, with_control: bool):

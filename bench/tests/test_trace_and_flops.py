@@ -11,10 +11,11 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops, trace as tr
+from bench import arch, flops, trace as tr
 
 DATA = Path(__file__).resolve().parent / "data" / "olmo1b_steps.json.gz"
 CFG = json.loads((Path(__file__).resolve().parents[1] / "configs" / "olmo1b.json").read_text())
+dense_arch = arch.load(CFG)
 PEAKS = json.loads((Path(__file__).resolve().parents[1] / "peaks.json").read_text())["devices"]["TPU v5 lite"]
 
 
@@ -93,10 +94,10 @@ def test_flops_and_bytes_at_olmo1b():
     # every weight is used once per token (the tied embedding as the
     # unembedding), plus the attention products over p + 1 keys
     dense = 2.0 * CFG["parameters"]
-    assert flops.token_flops(CFG, 0) == pytest.approx(dense + 4 * 16 * 16 * 128)
+    assert flops.sequence_flops(CFG, 0, 1) == pytest.approx(dense + 4 * 16 * 16 * 128)
     assert flops.sequence_flops(CFG, 10, 13) == pytest.approx(
-        sum(flops.token_flops(CFG, p) for p in (10, 11, 12)))
+        3 * dense + 4 * 16 * 16 * 128 * (11 + 12 + 13))
     # a decode step's products at 8 rows are bound by the codes' bytes
-    for k, n, x in flops.matmul_shapes(CFG):
+    for k, n, x in dense_arch._matmul_shapes(CFG):
         f, b = flops.dequant_matmul_cost(8, k, n, x)
         assert flops.roofline_s(f, b, PEAKS) == b / PEAKS["hbm_bytes_per_s"]
