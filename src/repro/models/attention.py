@@ -33,11 +33,17 @@ NEG_INF = -1e30
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, pos: jax.Array, theta: float,
+         rotary_dim: int | None = None) -> jax.Array:
     """x: (B, T, H, hd); pos: (T,) shared or (B, T) per-slot int32
     positions (ragged decode batches rotate every slot at its own
-    position)."""
+    position). ``rotary_dim`` rotates only each head's first
+    ``rotary_dim`` dimensions (partial rotary) and passes the rest
+    through."""
     hd = x.shape[-1]
+    if rotary_dim is not None and rotary_dim < hd:
+        rot = rope(x[..., :rotary_dim], pos, theta)
+        return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = pos.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
@@ -215,18 +221,26 @@ def write_kv_slot(cache: jax.Array, new: jax.Array, pos: jax.Array,
 
 def mlp_init(cfg: ArchConfig, key):
     k1, k2, k3 = jax.random.split(key, 3)
-    return {
-        "wi_gate": dense_init(k1, cfg.d_model, cfg.d_ff),
+    p = {
         "wi_up": dense_init(k2, cfg.d_model, cfg.d_ff),
         "wo": dense_init(k3, cfg.d_ff, cfg.d_model),
     }
+    if cfg.mlp_gated:
+        p["wi_gate"] = dense_init(k1, cfg.d_model, cfg.d_ff)
+    return p
 
 
 def mlp_apply(cfg: ArchConfig, p, x: jax.Array) -> jax.Array:
+    """Gated: ``wo(act(wi_gate x) * wi_up x)``; ungated:
+    ``wo(act(wi_up x))``."""
     dt = cfg.dtype
-    h = activation(cfg, dense(x, p["wi_gate"], dtype=dt)) \
-        * dense(x, p["wi_up"], dtype=dt)
-    return dense(h, p["wo"], dtype=dt)
+    with jax.named_scope("mlp"):
+        if cfg.mlp_gated:
+            h = activation(cfg, dense(x, p["wi_gate"], dtype=dt)) \
+                * dense(x, p["wi_up"], dtype=dt)
+        else:
+            h = activation(cfg, dense(x, p["wi_up"], dtype=dt))
+        return dense(h, p["wo"], dtype=dt)
 
 
 def attn_init(cfg: ArchConfig, key, *, cross: bool = False):
@@ -285,6 +299,7 @@ def self_attention(
 ):
     """Returns (attn_out, new_cache)."""
     theta = rope_theta if rope_theta is not None else cfg.rope_theta
+    rd = cfg.rotary_dim
     B, Tq, _ = x.shape
     if mode in ("full", "prefill"):
         q, k, v = project_qkv(cfg, p, x, x)
@@ -302,8 +317,8 @@ def self_attention(
                     "attention (the ring layout has no masked slots)")
             nv = jnp.asarray(pos, jnp.int32).reshape(-1)[0]
             q_pos = jnp.where(q_pos < nv, q_pos, jnp.int32(-1))
-        q = rope(q, q_pos, theta)
-        k = rope(k, q_pos, theta)
+        q = rope(q, q_pos, theta, rd)
+        k = rope(k, q_pos, theta, rd)
         out = chunked_attention(
             q, k, v, q_pos, q_pos, causal=True, window=window,
             chunk=cfg.attn_chunk, unroll=cfg.costing,
@@ -336,8 +351,8 @@ def self_attention(
                                 pos_vec[:, None]
                                 + jnp.arange(Tq, dtype=jnp.int32)[None, :],
                                 jnp.int32(-1))                 # (B, T)
-        q = rope(q, tok_pos, theta)
-        k_new = rope(k_new, tok_pos, theta)
+        q = rope(q, tok_pos, theta, rd)
+        k_new = rope(k_new, tok_pos, theta, rd)
         kn = jnp.swapaxes(k_new, 1, 2)                         # (B, K, T, hd)
         vn = jnp.swapaxes(v_new, 1, 2)
         # write the whole block FIRST, then attend: rejected verify rows
@@ -404,8 +419,8 @@ def self_attention(
     else:  # decode: ragged, native-layout, one batched kernel call
         q, k_new, v_new = project_qkv(cfg, p, x, x)
         pos_vec = decode_pos_vector(pos, B)                    # (B,)
-        q = rope(q, pos_vec[:, None], theta)
-        k_new = rope(k_new, pos_vec[:, None], theta)
+        q = rope(q, pos_vec[:, None], theta, rd)
+        k_new = rope(k_new, pos_vec[:, None], theta, rd)
         kn = jnp.swapaxes(k_new, 1, 2)                         # (B, K, 1, hd)
         vn = jnp.swapaxes(v_new, 1, 2)
         # inactive slots (pos < 0) write NOTHING: a mid-prefill slot's

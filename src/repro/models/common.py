@@ -70,7 +70,7 @@ class ArchConfig:
     """One architecture. ``cycle`` is the repeating block pattern; layers
     = len(cycle) * n_cycles + len(tail). Block kinds:
 
-    attn        full-attention decoder block (GQA + GLU MLP)
+    attn        full-attention decoder block (GQA + MLP, gated or not)
     swa         sliding-window attention block (window=cfg.window)
     global      full attention (gemma3 naming, distinct rope_theta)
     moe         attention + MoE FFN (full attn)
@@ -96,10 +96,15 @@ class ArchConfig:
     head_dim: int | None = None
     # attention
     rope_theta: float = 10_000.0
+    # rotate only the first rotary_dim dims of each head (pairs i and
+    # i + rotary_dim/2, as HF Nemotron's partial rotary); None = all
+    rotary_dim: int | None = None
     window: int = 0  # sliding window width for swa/local blocks
     qk_norm: bool = False
-    norm_type: str = "rmsnorm"  # rmsnorm | layernorm | nonparam_ln
-    act: str = "silu"
+    norm_type: str = "rmsnorm"  # rmsnorm | layernorm | layernorm1p | nonparam_ln
+    act: str = "silu"  # silu | gelu | relu2
+    # MLP: wo(act(wi_gate x) * wi_up x) when gated, else wo(act(wi_up x))
+    mlp_gated: bool = True
     attn_chunk: int = 1024  # online-softmax KV chunk
     # moe
     n_experts: int = 0
@@ -119,7 +124,8 @@ class ArchConfig:
     # vlm
     vision_tokens: int = 0
     d_vision: int = 0
-    # output
+    # input / output
+    embed_scale: bool = True  # multiply token embeddings by sqrt(d_model)
     tie_embeddings: bool = True
     logit_softcap: float = 0.0
     # compute dtype for activations
@@ -203,6 +209,10 @@ class ArchConfig:
         if small["n_heads"] % max(small["n_kv"], 1):
             small["n_kv"] = 1
         small.update(overrides)
+        if self.rotary_dim and "rotary_dim" not in overrides:
+            # keep the rotated share of each head
+            hd = small["head_dim"] or small["d_model"] // small["n_heads"]
+            small["rotary_dim"] = hd * self.rotary_dim // self.hd
         return dataclasses.replace(self, **small)
 
 
@@ -215,6 +225,8 @@ def norm_init(cfg: ArchConfig, d: int):
         return {"scale": jnp.ones((d,), jnp.float32)}
     if cfg.norm_type == "layernorm":
         return {"scale": jnp.ones((d,), jnp.float32), "bias": jnp.zeros((d,), jnp.float32)}
+    if cfg.norm_type == "layernorm1p":  # Nemotron: applied as 1 + scale
+        return {"scale": jnp.zeros((d,), jnp.float32), "bias": jnp.zeros((d,), jnp.float32)}
     if cfg.norm_type == "nonparam_ln":  # OLMo: LN without affine params
         return {}
     raise ValueError(cfg.norm_type)
@@ -231,6 +243,8 @@ def apply_norm(cfg: ArchConfig, p, x: jax.Array) -> jax.Array:
         y = (xf - mu) * jax.lax.rsqrt(var + 1e-5)
         if cfg.norm_type == "layernorm":
             y = y * p["scale"] + p["bias"]
+        elif cfg.norm_type == "layernorm1p":
+            y = y * (1.0 + p["scale"]) + p["bias"]
     return y.astype(x.dtype)
 
 
@@ -239,6 +253,8 @@ def activation(cfg: ArchConfig, x: jax.Array) -> jax.Array:
         return jax.nn.silu(x)
     if cfg.act == "gelu":
         return jax.nn.gelu(x)
+    if cfg.act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(cfg.act)
 
 

@@ -87,12 +87,15 @@ class Model:
     def _embed(self, params, tokens):
         cfg = self.cfg
         x = embed_lookup(params["embed"], tokens).astype(cfg.dtype)
+        if not cfg.embed_scale:
+            return x
         return x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
 
     def _unembed(self, params, x):
         cfg = self.cfg
         w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = dense(x.astype(jnp.float32), w, dtype=jnp.float32)
+        with jax.named_scope("unembed"):
+            logits = dense(x.astype(jnp.float32), w, dtype=jnp.float32)
         return softcap(logits, cfg.logit_softcap)
 
     # -- entry points --------------------------------------------------------

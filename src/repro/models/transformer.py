@@ -99,8 +99,8 @@ def block_apply(cfg: ArchConfig, kind: str, p, x, *, mode: str, cache, pos, enc_
             q, k, v = attn.project_qkv(cfg, p["attn"], h, h)
             T = h.shape[1]
             qpos = jnp.arange(T, dtype=jnp.int32)
-            q = attn.rope(q, qpos, cfg.rope_theta)
-            k = attn.rope(k, qpos, cfg.rope_theta)
+            q = attn.rope(q, qpos, cfg.rope_theta, cfg.rotary_dim)
+            k = attn.rope(k, qpos, cfg.rope_theta, cfg.rotary_dim)
             o = attn.chunked_attention(
                 q, k, v, qpos, qpos, causal=False, window=0,
                 chunk=cfg.attn_chunk, unroll=cfg.costing,
@@ -109,16 +109,17 @@ def block_apply(cfg: ArchConfig, kind: str, p, x, *, mode: str, cache, pos, enc_
                           dtype=cfg.dtype)
             new_cache = None
         else:
-            a_out, new_cache = attn.self_attention(
-                cfg,
-                p["attn"],
-                h,
-                mode=mode,
-                window=_attn_window(cfg, kind),
-                cache=cache,
-                pos=pos,
-                rope_theta=_attn_theta(cfg, kind),
-            )
+            with jax.named_scope("attn"):
+                a_out, new_cache = attn.self_attention(
+                    cfg,
+                    p["attn"],
+                    h,
+                    mode=mode,
+                    window=_attn_window(cfg, kind),
+                    cache=cache,
+                    pos=pos,
+                    rope_theta=_attn_theta(cfg, kind),
+                )
         x = x + a_out
         h2 = apply_norm(cfg, p["norm2"], x)
         x = x + attn.mlp_apply(cfg, p["mlp"], h2)

@@ -70,6 +70,18 @@ def test_serving_spec_expert_dim_preferred(mesh):
     assert tuple(spec)[-1] == "model"
 
 
+def test_serving_spec_minitron_leaves(mesh):
+    """The Nemotron block's own leaves: the untied head and the ungated
+    up projection shard their output dim; LayerNorm1P's stacked scale
+    and bias replicate."""
+    assert serving_spec_for_param("lm_head", (3072, 256000), mesh) == P(None, "model")
+    assert serving_spec_for_param("decoder/cycles/0_attn/mlp/wi_up",
+                                  (4, 3072, 9216), mesh) == P(None, None, "model")
+    for leaf in ("norm1/scale", "norm1/bias"):
+        assert serving_spec_for_param(f"decoder/cycles/0_attn/{leaf}",
+                                      (4, 3072), mesh) == P()
+
+
 def test_serving_spec_replicates_everything_else(mesh, mesh1):
     assert serving_spec_for_param("final_norm/scale", (64,), mesh) == P()
     assert serving_spec_for_param("b", (), mesh) == P()
@@ -264,3 +276,43 @@ def test_sharded_moe_speculative_and_pool_token_identity():
         "the pooled state must start on the mesh: one chunk-step executable"
     assert out["pool_upgrades"] > 0 and out["pool_all_enqueue_only"], \
         "upgrades must stay enqueue-only (zero-stall) on the mesh"
+
+
+@pytest.mark.slow
+def test_sharded_minitron_pool_token_identity():
+    """Minitron's block (ungated relu² MLP, untied head, LayerNorm1P
+    with bias, partial rotary, a group of 3 query heads per KV head) in
+    the slot pool on a 4-way model axis: the same tokens as one device,
+    with quantized residency, one decode executable and the untied head
+    sharded on its vocabulary."""
+    out = _run_sub(_PRELUDE + """
+    from repro.launch.mesh import make_serving_mesh
+
+    cfg = get_config("minitron-4b").reduced(n_layers=2, d_model=64, n_heads=6,
+                                            n_kv=2, head_dim=16, d_ff=96,
+                                            vocab=128)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prog = divide(params)
+    blob = wire.encode(prog)
+    prompts = [jax.random.randint(jax.random.PRNGKey(40 + i), (L,), 0,
+                                  cfg.vocab).astype(jnp.int32)
+               for i, L in enumerate([6, 9, 7])]
+
+    def pool(m):
+        sess = Session(blob, BandwidthTrace.constant(2e5))
+        return sess.run_serving_pool(model, prog, prompts=prompts,
+                                     max_new_tokens=6, n_slots=2,
+                                     resident="quantized", mesh=m)
+
+    p1, p2 = pool(None), pool(make_serving_mesh(4))
+    head = p2.server.params["lm_head"].q
+    print(json.dumps({
+        "tokens_equal": all(p1.tokens[rid] == p2.tokens[rid]
+                            for rid in p1.tokens),
+        "decode_cache": p2.server.decode_cache_size(),
+        "head_spec": str(head.sharding.spec)}))
+    """)
+    assert out["tokens_equal"]
+    assert out["decode_cache"] == 1
+    assert out["head_spec"] == str(P(None, "model"))

@@ -21,8 +21,10 @@ from repro.kernels import bitplane, decode_attention, dequant_matmul, \
 from repro.models.model import build_model
 
 OLMO = get_config("olmo-1b")
+MINITRON = get_config("minitron-4b")
 S, HD = 2048, OLMO.hd
 CHUNK = 8  # the slot pool's default prefill chunk
+SLOTS = 64  # the minitron4b.offline cell's pool
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +79,40 @@ def test_dequant_matmul_compiles(shape_on_chip, no_persistent_cache,
         shape_on_chip((1, 1), jnp.float32),
         shape_on_chip((1, 1), jnp.float32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("k,n", [(MINITRON.d_model, MINITRON.vocab),   # untied head
+                                 (MINITRON.d_ff, MINITRON.d_model)])  # MLP down
+@pytest.mark.parametrize("m", [SLOTS, SLOTS * CHUNK])  # decode, prefill chunk
+def test_dequant_matmul_compiles_at_minitron(shape_on_chip, no_persistent_cache,
+                                             m, k, n):
+    hlo = _compile_for_chip(
+        lambda x, q, s, o: dequant_matmul.dequant_matmul(x, q, s, o,
+                                                         interpret=False),
+        shape_on_chip((m, k), jnp.float32 if n == MINITRON.vocab else jnp.bfloat16),
+        shape_on_chip((k, n), jnp.uint16),
+        shape_on_chip((1, 1), jnp.float32),
+        shape_on_chip((1, 1), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [1, CHUNK])  # flash_decode, flash_verify
+def test_attention_compiles_at_minitron_gqa(shape_on_chip, no_persistent_cache, rows):
+    """24 query heads over 8 KV heads (a group of 3, padded to the
+    8-row sublane) for 64 slots of 2048 positions."""
+    kv = shape_on_chip((SLOTS, MINITRON.n_kv, S, MINITRON.hd), jnp.bfloat16)
+    kp = shape_on_chip((SLOTS, S), jnp.int32)
+    if rows == 1:
+        fn = lambda q, k, v, kp, qp: decode_attention.flash_decode(  # noqa: E731
+            q, k, v, kp, qp, interpret=False)
+        q = shape_on_chip((SLOTS, MINITRON.n_heads, MINITRON.hd), jnp.bfloat16)
+        qp = shape_on_chip((SLOTS,), jnp.int32)
+    else:
+        fn = lambda q, k, v, kp, qp: verify_attention.flash_verify(  # noqa: E731
+            q, k, v, kp, qp, interpret=False)
+        q = shape_on_chip((SLOTS, rows, MINITRON.n_heads, MINITRON.hd), jnp.bfloat16)
+        qp = shape_on_chip((SLOTS, rows), jnp.int32)
+    assert "tpu_custom_call" in _compile_for_chip(fn, q, kv, kv, kp, qp)
 
 
 @pytest.mark.parametrize("b", [1, 4, 8])
