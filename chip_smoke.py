@@ -238,7 +238,30 @@ def check_kernels(smoke: Smoke, cfg, blob: bytes) -> None:
                      oracle(ora, qT, k, v, k_pos, tpos), rows >= 0)
         smoke.check(f"{name} B={B} T={T} H={H} S={S} hd={hd}",
                     e <= FLOAT_TOL, f"relative error {e:.3e} over live rows")
-    del k, v
+
+    # the in-place row write into layer 1 of a stack of two, byte for
+    # byte against its oracle, and the attention kernels reading that
+    # layer by index exactly as they read it sliced out
+    layer = jnp.int32(1)
+    for new_rows in (np.asarray(q_pos)[:, None], rows):
+        kn = jax.random.normal(next(key), (B, H, new_rows.shape[1], hd),
+                               jnp.bfloat16)
+        r = jnp.asarray(new_rows, jnp.int32)
+        got = ops.kv_write(jnp.stack([v, k]), jnp.stack([k, v]), kn, -kn, r,
+                           layer)
+        want = ref.kv_write_ref(jnp.stack([v, k]), jnp.stack([k, v]), kn,
+                                -kn, r, layer)
+        same = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
+        smoke.check(f"kv_write B={B} T={new_rows.shape[1]} H={H} S={S} "
+                    f"hd={hd}", same, "bytes equal to the oracle's")
+    ks, vs = got
+    for name, fn, qq, qp in (("flash_decode", ops.flash_decode, q1, q_pos),
+                             ("flash_verify", ops.flash_verify, qT, tpos)):
+        by_index = fn(qq, ks, vs, k_pos, qp, layer)
+        sliced = fn(qq, ks[1], vs[1], k_pos, qp)
+        smoke.check(f"{name} by layer index", bool(jnp.array_equal(
+            by_index, sliced)), "equal to the layer sliced out")
+    del k, v, ks, vs, got, want
 
     # plane OR over the whole olmo-1b uint16 buffer, with the segment
     # table of the real store layout and a different shift per tensor

@@ -55,8 +55,9 @@ def _pick_block(S: int, bs: int) -> int:
     return 0
 
 
-def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+def _kernel(layer_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
             m_ref, l_ref, acc_ref, *, n_s: int, window: int, softcap: float):
+    del layer_ref  # consumed by the K/V index maps
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -95,15 +96,42 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
+def stacked_kv(k, v, k_pos, layer, bs: int):
+    """Shared operand prep of the decode-family kernels. Returns
+    ``(k, v, k_pos, layer, bs)`` with k/v as an (L, B, Kh, S, hd) stack,
+    ``layer`` a (1,) int32 index into it (scalar-prefetched into the
+    K/V index maps, so no layer is ever sliced out) and ``bs`` the
+    sequence block. A plain (B, Kh, S, hd) cache (``layer`` None) is a
+    stack of one.
+
+    The block shrinks to a sublane-aligned divisor of S (no padding, no
+    copies); only a divisor-hostile S (prime-ish, or only misaligned or
+    tiny divisors) pads the tail block, of the one layer read: keys
+    padded with k_pos = -1 are masked exactly like empty slots."""
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    S = k.shape[3]
+    d = _pick_block(S, bs)
+    if d:
+        return k, v, k_pos, layer, d
+    pad = ((0, 0),) * 3 + ((0, (-S) % bs), (0, 0))
+    k = jnp.pad(jax.lax.dynamic_slice_in_dim(k, layer[0], 1), pad)
+    v = jnp.pad(jax.lax.dynamic_slice_in_dim(v, layer[0], 1), pad)
+    k_pos = jnp.pad(k_pos, ((0, 0), (0, (-S) % bs)), constant_values=-1)
+    return k, v, k_pos, jnp.zeros((1,), jnp.int32), bs
+
+
 @functools.partial(
     jax.jit, static_argnames=("window", "softcap", "bs", "interpret")
 )
 def flash_decode(
     q: jax.Array,        # (B, H, hd) one new token's queries per slot
-    k: jax.Array,        # (B, Kh, S, hd) cache, native layout
-    v: jax.Array,        # (B, Kh, S, hd)
+    k: jax.Array,        # (B, Kh, S, hd) cache, native layout, or the
+    v: jax.Array,        #   stacked (L, B, Kh, S, hd) cache with `layer`
     k_pos: jax.Array,    # (B, S) int32; negative = empty slot
     q_pos: jax.Array,    # (B,) int32; negative = free pool slot
+    layer=None,          # () int32 index into a stacked cache
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -111,22 +139,9 @@ def flash_decode(
     interpret: bool = False,
 ) -> jax.Array:
     B, H, hd = q.shape
-    Kh, S = k.shape[1], k.shape[2]
+    k, v, k_pos, layer, bs = stacked_kv(k, v, k_pos, layer, bs)
+    Kh, S = k.shape[2], k.shape[3]
     G = H // Kh
-
-    # prefer shrinking the block to a sublane-aligned divisor of S (no
-    # padding, no copies); if S is divisor-hostile (prime-ish, or only
-    # misaligned/tiny divisors) fall back to padding the tail block —
-    # keys padded with k_pos = -1 are masked exactly like empty slots
-    d = _pick_block(S, bs)
-    if d:
-        bs = d
-    else:
-        pad_s = (-S) % bs
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        k_pos = jnp.pad(k_pos, ((0, 0), (0, pad_s)), constant_values=-1)
-        S = S + pad_s
     n_s = S // bs
 
     # pad G to the 8-row sublane so the score tile is vreg-aligned
@@ -140,23 +155,28 @@ def flash_decode(
     pos2 = k_pos.astype(jnp.int32).reshape(B, n_s, 1, bs)
     qpos2 = q_pos.astype(jnp.int32).reshape(B, 1, 1)
 
+    kv = pl.BlockSpec((None, 1, 1, bs, hd),
+                      lambda b, h, s, layer: (layer[0], b, h, s, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, n_s=n_s, window=window, softcap=softcap),
-        grid=(B, Kh, n_s),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1), lambda b, h, s: (b, 0, 0)),
-            pl.BlockSpec((1, 1, Gp, hd), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, bs), lambda b, h, s: (b, s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Gp, hd), lambda b, h, s: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Kh, n_s),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1), lambda b, h, s, _: (b, 0, 0)),
+                pl.BlockSpec((1, 1, Gp, hd), lambda b, h, s, _: (b, h, 0, 0)),
+                kv,
+                kv,
+                pl.BlockSpec((1, 1, 1, bs), lambda b, h, s, _: (b, s, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, Gp, hd),
+                                   lambda b, h, s, _: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((Gp, 1), jnp.float32),
+                pltpu.VMEM((Gp, 1), jnp.float32),
+                pltpu.VMEM((Gp, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, Kh, Gp, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((Gp, 1), jnp.float32),
-            pltpu.VMEM((Gp, 1), jnp.float32),
-            pltpu.VMEM((Gp, hd), jnp.float32),
-        ],
         interpret=interpret,
-    )(qpos2, qg, k, v, pos2)
+    )(layer, qpos2, qg, k, v, pos2)
     return out[:, :, :G, :].reshape(B, H, hd)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.kernels import dequant_matmul as _dqm
 from repro.kernels import bitplane as _bp
 from repro.kernels import decode_attention as _da
+from repro.kernels import kv_write as _kvw
 from repro.kernels import verify_attention as _va
 
 # Dispatch counts per public kernel entry point. Reset freely; purely
@@ -110,20 +111,48 @@ def plane_extract(q, *, bits, before, width, **kw):
     return _bp.plane_extract(q, bits=bits, before=before, width=width, **kw)
 
 
-def flash_decode(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0, **kw):
+def kv_write(k, v, k_new, v_new, rows, layer=None):
+    """The model's KV row write: new rows k_new/v_new (B, Kh, T, hd)
+    into the cache at per-row positions ``rows`` (B, T) int32 (negative
+    = masked: the row writes nothing). k/v are the stacked
+    (L, B, Kh, S, hd) cache with ``layer`` its () int32 index, or one
+    (B, Kh, S, hd) cache with ``layer`` None. Returns the updated
+    (k, v), the buffers that came in (updated in place when the caller
+    owns them). On TPU this is the Pallas kernel, for any cache length
+    its DMA windows can tile; elsewhere the jnp oracle."""
+    _count("kv_write")
+    one = layer is None
+    if one:
+        k, v, layer = k[None], v[None], 0
+    if not _interpret_default() and k.shape[3] % _kvw.ALIGN == 0:
+        k, v = _kvw.kv_write(k, v, k_new, v_new, rows, layer,
+                             interpret=False)
+    else:
+        from repro.kernels import ref as _ref
+
+        k, v = _ref.kv_write_ref(k, v, k_new, v_new, rows, layer)
+    return (k[0], v[0]) if one else (k, v)
+
+
+def flash_decode(q, k, v, k_pos, q_pos, layer=None, *, window=0,
+                 softcap=0.0, **kw):
     """Ragged batched decode attention: q (B, H, hd); k/v in the native
-    (B, Kh, S, hd) cache layout; k_pos (B, S); q_pos (B,)."""
+    (B, Kh, S, hd) cache layout, or stacked (L, B, Kh, S, hd) with
+    ``layer`` the () int32 index of the layer to read; k_pos (B, S);
+    q_pos (B,)."""
     _count("flash_decode")
     kw.setdefault("interpret", _interpret_default())
     return _da.flash_decode(
-        q, k, v, k_pos, q_pos, window=window, softcap=softcap, **kw
+        q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap, **kw
     )
 
 
-def decode_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
+def decode_attention(q, k, v, k_pos, q_pos, layer=None, *, window=0,
+                     softcap=0.0):
     """The model's per-step decode-attention entry point (same ragged
-    operands as :func:`flash_decode`). On TPU this is the Pallas flash
-    kernel; elsewhere it is the vectorized jnp oracle — interpret-mode
+    operands as :func:`flash_decode`, stacked caches and ``layer``
+    included). On TPU this is the Pallas flash kernel; elsewhere it is
+    the vectorized jnp oracle — interpret-mode
     Pallas unrolls the (B, Kh, S/bs) grid into the jaxpr, which turns a
     batched decode step into O(B) staged kernel bodies and defeats the
     whole point of continuous batching on CPU CI. Both consume the
@@ -134,28 +163,31 @@ def decode_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
     _count("decode_attention")
     if not _interpret_default():
         return _da.flash_decode(
-            q, k, v, k_pos, q_pos, window=window, softcap=softcap,
+            q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap,
             interpret=False
         )
     from repro.kernels import ref as _ref
 
     return _ref.flash_decode_ref(
-        q, k, v, k_pos, q_pos, window=window, softcap=softcap
+        q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap
     ).astype(q.dtype)
 
 
-def flash_verify(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0, **kw):
+def flash_verify(q, k, v, k_pos, q_pos, layer=None, *, window=0,
+                 softcap=0.0, **kw):
     """Ragged draft-block verify attention: q (B, T, H, hd); k/v in the
-    native (B, Kh, S, hd) cache layout; k_pos (B, S); q_pos (B, T)
-    per-token positions (negative = masked row)."""
+    native (B, Kh, S, hd) cache layout, or stacked with ``layer`` as in
+    :func:`flash_decode`; k_pos (B, S); q_pos (B, T) per-token
+    positions (negative = masked row)."""
     _count("flash_verify")
     kw.setdefault("interpret", _interpret_default())
     return _va.flash_verify(
-        q, k, v, k_pos, q_pos, window=window, softcap=softcap, **kw
+        q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap, **kw
     )
 
 
-def verify_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
+def verify_attention(q, k, v, k_pos, q_pos, layer=None, *, window=0,
+                     softcap=0.0):
     """The model's verify-step attention entry point: T = k+1 draft
     queries per slot against the same native cache, one pass. On TPU
     this is the Pallas flash_verify kernel; elsewhere it is the jnp
@@ -167,20 +199,22 @@ def verify_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
     _count("verify_attention")
     if not _interpret_default():
         return _va.flash_verify(
-            q, k, v, k_pos, q_pos, window=window, softcap=softcap,
+            q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap,
             interpret=False
         )
     from repro.kernels import ref as _ref
 
     return _ref.flash_verify_ref(
-        q, k, v, k_pos, q_pos, window=window, softcap=softcap
+        q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap
     ).astype(q.dtype)
 
 
-def prefill_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
+def prefill_attention(q, k, v, k_pos, q_pos, layer=None, *, window=0,
+                      softcap=0.0):
     """The model's chunked-prefill attention entry point: a (B, chunk)
     block of ragged prompt queries per slot against the same native
-    (B, Kh, S, hd) cache, one pass. Operand-wise this is
+    (B, Kh, S, hd) cache (or stacked, with ``layer``), one pass.
+    Operand-wise this is
     :func:`verify_attention` — q (B, T, H, hd), k_pos (B, S), q_pos
     (B, T) per-token positions with negative = masked row — the
     difference is what the rows MEAN: q_pos rows carry per-slot chunk
@@ -194,13 +228,13 @@ def prefill_attention(q, k, v, k_pos, q_pos, *, window=0, softcap=0.0):
     _count("prefill_attention")
     if not _interpret_default():
         return _va.flash_verify(
-            q, k, v, k_pos, q_pos, window=window, softcap=softcap,
+            q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap,
             interpret=False
         )
     from repro.kernels import ref as _ref
 
     return _ref.flash_prefill_ref(
-        q, k, v, k_pos, q_pos, window=window, softcap=softcap
+        q, k, v, k_pos, q_pos, layer, window=window, softcap=softcap
     ).astype(q.dtype)
 
 

@@ -48,17 +48,47 @@ def plane_unpack_ref(packed: jax.Array, width: int, dtype) -> jax.Array:
     return vals.reshape(-1).astype(dtype)
 
 
+def kv_write_ref(k: jax.Array, v: jax.Array, k_new: jax.Array,
+                 v_new: jax.Array, rows: jax.Array, layer: jax.Array):
+    """Write new K/V rows into one layer of a stacked cache.
+
+    k/v: (L, B, Kh, S, hd); k_new/v_new: (B, Kh, T, hd); rows: (B, T)
+    int32 position of each new row (negative or >= S = masked: the
+    row writes nothing); layer: () int32. Returns the updated (k, v);
+    every byte outside the live rows is unchanged."""
+    S = k.shape[3]
+    r = jnp.where((rows >= 0) & (rows < S), rows, S)   # S: dropped
+    b = jnp.arange(rows.shape[0])[:, None]
+
+    def put(c, new):
+        # advanced indices around a slice: the (B, T) index dims lead
+        return c.at[layer, b, :, r, :].set(
+            jnp.swapaxes(new, 1, 2).astype(c.dtype), mode="drop")
+
+    return put(k, k_new), put(v, v_new)
+
+
+def _layer_of(k: jax.Array, v: jax.Array, layer):
+    """The (B, Kh, S, hd) cache an attention oracle reads: k/v as given,
+    or layer ``layer`` of a stacked (L, B, Kh, S, hd) cache."""
+    if layer is None:
+        return k, v
+    return k[layer], v[layer]
+
+
 def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                     k_pos: jax.Array, q_pos: jax.Array,
+                     k_pos: jax.Array, q_pos: jax.Array, layer=None,
                      *, window: int = 0, softcap: float = 0.0) -> jax.Array:
     """Ragged batched single-token GQA decode attention.
 
-    q: (B, H, hd); k/v: (B, Kh, S, hd) native cache layout;
+    q: (B, H, hd); k/v: (B, Kh, S, hd) native cache layout, or the
+    stacked (L, B, Kh, S, hd) cache with ``layer`` its () int32 index;
     k_pos: (B, S) int32 per-slot cache positions (negative = empty
     slot); q_pos: (B,) int32 per-slot query position (negative = free
     pool slot: every key is masked and the output row is meaningless).
     Returns (B, H, hd).
     """
+    k, v = _layer_of(k, v, layer)
     B, H, hd = q.shape
     Kh, S = k.shape[1], k.shape[2]
     G = H // Kh
@@ -77,12 +107,13 @@ def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def flash_verify_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                     k_pos: jax.Array, q_pos: jax.Array,
+                     k_pos: jax.Array, q_pos: jax.Array, layer=None,
                      *, window: int = 0, softcap: float = 0.0) -> jax.Array:
     """Ragged batched draft-block verify attention (T = k+1 queries per
     slot against one native-layout cache).
 
-    q: (B, T, H, hd); k/v: (B, Kh, S, hd); k_pos: (B, S) per-slot cache
+    q: (B, T, H, hd); k/v: (B, Kh, S, hd), or stacked with ``layer``
+    as in :func:`flash_decode_ref`; k_pos: (B, S) per-slot cache
     positions; q_pos: (B, T) int32 *per-token* query positions (negative
     = masked row — draft padding or a free pool slot). Returns
     (B, T, H, hd).
@@ -95,6 +126,7 @@ def flash_verify_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     rather than tolerance. T is small (k+1), so the sequential map costs
     nothing here; the TPU kernel amortizes the cache pass instead.
     """
+    k, v = _layer_of(k, v, layer)
     qt = jnp.swapaxes(q, 0, 1)        # (T, B, H, hd)
     qpt = jnp.swapaxes(q_pos, 0, 1)   # (T, B)
 
@@ -108,7 +140,7 @@ def flash_verify_ref(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def flash_prefill_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                      k_pos: jax.Array, q_pos: jax.Array,
+                      k_pos: jax.Array, q_pos: jax.Array, layer=None,
                       *, window: int = 0, softcap: float = 0.0) -> jax.Array:
     """Ragged chunked-prefill attention oracle: a (B, chunk) block of
     prompt queries per slot against one native-layout cache.
@@ -127,5 +159,5 @@ def flash_prefill_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     so a TPU prefill kernel can diverge from the verify kernel without
     touching callers.
     """
-    return flash_verify_ref(q, k, v, k_pos, q_pos,
+    return flash_verify_ref(q, k, v, k_pos, q_pos, layer,
                             window=window, softcap=softcap)

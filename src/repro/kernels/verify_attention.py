@@ -39,11 +39,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 # one block-size policy for both decode-family kernels: a tuning change
 # there must not desynchronize the verify kernel's padding behavior
-from repro.kernels.decode_attention import NEG_INF, _pick_block
+from repro.kernels.decode_attention import NEG_INF, stacked_kv
 
 
-def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+def _kernel(layer_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
             m_ref, l_ref, acc_ref, *, n_s: int, window: int, softcap: float):
+    del layer_ref  # consumed by the K/V index maps
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -89,10 +90,11 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 )
 def flash_verify(
     q: jax.Array,        # (B, T, H, hd) draft-block queries per slot
-    k: jax.Array,        # (B, Kh, S, hd) cache, native layout
-    v: jax.Array,        # (B, Kh, S, hd)
+    k: jax.Array,        # (B, Kh, S, hd) cache, native layout, or the
+    v: jax.Array,        #   stacked (L, B, Kh, S, hd) cache with `layer`
     k_pos: jax.Array,    # (B, S) int32; negative = empty slot
     q_pos: jax.Array,    # (B, T) int32 per-token; negative = masked row
+    layer=None,          # () int32 index into a stacked cache
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -100,18 +102,9 @@ def flash_verify(
     interpret: bool = False,
 ) -> jax.Array:
     B, T, H, hd = q.shape
-    Kh, S = k.shape[1], k.shape[2]
+    k, v, k_pos, layer, bs = stacked_kv(k, v, k_pos, layer, bs)
+    Kh, S = k.shape[2], k.shape[3]
     G = H // Kh
-
-    d = _pick_block(S, bs)
-    if d:
-        bs = d
-    else:
-        pad_s = (-S) % bs
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        k_pos = jnp.pad(k_pos, ((0, 0), (0, pad_s)), constant_values=-1)
-        S = S + pad_s
     n_s = S // bs
 
     # rows: draft-token-major, query-group-minor — (t, g) -> t * G + g;
@@ -130,24 +123,29 @@ def flash_verify(
     pos2 = k_pos.astype(jnp.int32).reshape(B, n_s, 1, bs)
     qpos_rows = qpos_rows[:, :, None]                           # (B, Rp, 1)
 
+    kv = pl.BlockSpec((None, 1, 1, bs, hd),
+                      lambda b, h, s, layer: (layer[0], b, h, s, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, n_s=n_s, window=window, softcap=softcap),
-        grid=(B, Kh, n_s),
-        in_specs=[
-            pl.BlockSpec((1, Rp, 1), lambda b, h, s: (b, 0, 0)),
-            pl.BlockSpec((1, 1, Rp, hd), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, bs), lambda b, h, s: (b, s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Rp, hd), lambda b, h, s: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Kh, n_s),
+            in_specs=[
+                pl.BlockSpec((1, Rp, 1), lambda b, h, s, _: (b, 0, 0)),
+                pl.BlockSpec((1, 1, Rp, hd), lambda b, h, s, _: (b, h, 0, 0)),
+                kv,
+                kv,
+                pl.BlockSpec((1, 1, 1, bs), lambda b, h, s, _: (b, s, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, Rp, hd),
+                                   lambda b, h, s, _: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((Rp, 1), jnp.float32),
+                pltpu.VMEM((Rp, 1), jnp.float32),
+                pltpu.VMEM((Rp, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, Kh, Rp, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((Rp, 1), jnp.float32),
-            pltpu.VMEM((Rp, 1), jnp.float32),
-            pltpu.VMEM((Rp, hd), jnp.float32),
-        ],
         interpret=interpret,
-    )(qpos_rows, qg, k, v, pos2)
+    )(layer, qpos_rows, qg, k, v, pos2)
     out = out[:, :, :R, :].reshape(B, Kh, T, G, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, T, H, hd)

@@ -194,24 +194,18 @@ def ring_positions(window: int, pos: jax.Array) -> jax.Array:
 
 
 def write_kv_slot(cache: jax.Array, new: jax.Array, pos: jax.Array,
-                  active: jax.Array | None = None) -> jax.Array:
-    """Write a token block's K or V into the native cache at each slot's
-    own position. cache: (B, K, S, hd); new: (B, K, T, hd) — T = 1 for a
-    decode step, T = k+1 contiguous rows for a verify block; pos: (B,)
-    int32 (clamped into range, so a free slot's ``-1`` writes harmlessly
-    at 0 — its row is fully masked anyway). ``active`` (B,) bool makes
-    the write a per-slot no-op instead (the verify path uses it so a
-    masked slot's cache row stays byte-identical, which is what lets the
-    rollback invariant be tested at equality)."""
-    def upd(c, u, p, a=None):
+                  active: jax.Array) -> jax.Array:
+    """Write one row of K or V per slot into a native ring cache.
+    cache: (B, K, W, hd); new: (B, K, 1, hd); pos: (B,) int32 ring slot;
+    ``active`` (B,) bool makes the write a per-slot no-op instead, so a
+    masked slot's cache row stays byte-identical (which is what lets the
+    rollback invariant be tested at equality). Positional caches take
+    ``kernels.ops.kv_write`` instead, in place."""
+    def upd(c, u, p, a):
         u = u.astype(c.dtype)
-        if a is not None:
-            old = lax.dynamic_slice(c, (0, p, 0), u.shape)
-            u = jnp.where(a, u, old)
-        return lax.dynamic_update_slice(c, u, (0, p, 0))
+        old = lax.dynamic_slice(c, (0, p, 0), u.shape)
+        return lax.dynamic_update_slice(c, jnp.where(a, u, old), (0, p, 0))
 
-    if active is None:
-        return jax.vmap(upd)(cache, new, pos)
     return jax.vmap(upd)(cache, new, pos, active)
 
 
@@ -291,13 +285,16 @@ def self_attention(
     *,
     mode: str,  # full | prefill | prefill_chunk | verify | decode
     window: int,
-    cache,  # {"k","v"} native (B, K, S|W, hd) or None
+    cache,  # {"k","v"} native (B, K, S|W, hd), stacked (L, B, K, S, hd)
+            # with `layer`, or None
     pos,  # decode/verify: scalar or (B,) int32 per-slot positions;
           # prefill_chunk: (B, T) per-token positions (negative = masked);
           # prefill: optional (B,) valid lengths for bucket-padded prompts
     rope_theta: float | None = None,
+    layer=None,  # () int32: this layer's index into a stacked cache
 ):
-    """Returns (attn_out, new_cache)."""
+    """Returns (attn_out, new_cache); with ``layer``, new_cache is the
+    whole stacked cache with this layer's new rows written."""
     theta = rope_theta if rope_theta is not None else cfg.rope_theta
     rd = cfg.rotary_dim
     B, Tq, _ = x.shape
@@ -332,10 +329,11 @@ def self_attention(
                 # one transpose at prefill; decode never transposes
                 new_cache = {"k": jnp.swapaxes(k, 1, 2),
                              "v": jnp.swapaxes(v, 1, 2)}
-    elif mode in ("verify", "prefill_chunk"):
-        # One multi-row pass per slot: T = k+1 draft tokens (verify) or
-        # a (B, chunk) block of ragged prompt positions (chunked
-        # prefill, writing prompt KV straight into the pooled cache).
+    else:
+        # decode (T = 1), verify (T = k + 1 draft tokens) or
+        # prefill_chunk (a (B, chunk) block of ragged prompt positions,
+        # written straight into the pooled cache): write the new rows,
+        # then attend, in one multi-row pass per slot.
         q, k_new, v_new = project_qkv(cfg, p, x, x)
         if mode == "prefill_chunk":
             # per-token positions arrive precomputed: row t of slot b
@@ -345,8 +343,9 @@ def self_attention(
             tok_pos = jnp.asarray(pos, jnp.int32)              # (B, T)
         else:
             pos_vec = decode_pos_vector(pos, B)                # (B,) base
-            # per-token positions; a negative base (free pool slot)
-            # keeps every row masked instead of walking into valid range
+            # per-token positions; a negative base (free pool slot, or a
+            # mid-prefill slot riding a decode step) keeps every row
+            # masked instead of walking into valid range
             tok_pos = jnp.where(pos_vec[:, None] >= 0,
                                 pos_vec[:, None]
                                 + jnp.arange(Tq, dtype=jnp.int32)[None, :],
@@ -362,8 +361,11 @@ def self_attention(
         # (free/finished slots, ragged padding) write NOTHING — their
         # cache rows stay byte-identical.
         if window:
+            # ring size comes from the cache (it may be over-allocated
+            # beyond the attention window for speculative rounds and
+            # prefill chunks); the window mask itself is positional
             ring = cache["k"].shape[2]
-            if ring < window + Tq:
+            if Tq > 1 and ring < window + Tq:
                 raise ValueError(
                     f"multi-row writes over a ring cache need ring >= "
                     f"window + T ({window} + {Tq}), got {ring}: the block "
@@ -376,79 +378,32 @@ def self_attention(
                 k_cache = write_kv_slot(k_cache, kn[:, :, t:t + 1], wp, live)
                 v_cache = write_kv_slot(v_cache, vn[:, :, t:t + 1], wp, live)
             # last written position per slot (-1 if fully masked): for a
-            # verify block this is pos + T - 1; for a chunk, off + n - 1
-            head = jnp.max(tok_pos, axis=1)
-            k_pos = ring_positions(ring, head)                 # (B, ring)
+            # verify block this is pos + T - 1; for a chunk, off + n - 1;
             # ring_positions(-1) is all-negative, so a masked slot's
             # whole ring stays invisible
-        elif mode == "prefill_chunk":
-            # per-row masked writes: a short final chunk must NOT write
-            # its padded tail — a T-wide block write starting at the
-            # last prompt position would CLAMP near the cache end
-            # (dynamic_update_slice shifts the start to S - T) and drag
-            # garbage onto real prompt rows. T single-row writes never
-            # clamp (every live row < max_len) and leave masked rows
-            # byte-identical.
-            k_cache, v_cache = cache["k"], cache["v"]
-            for t in range(Tq):
-                wp = jnp.maximum(tok_pos[:, t], 0)
-                live = tok_pos[:, t] >= 0
-                k_cache = write_kv_slot(k_cache, kn[:, :, t:t + 1], wp, live)
-                v_cache = write_kv_slot(v_cache, vn[:, :, t:t + 1], wp, live)
-            S = k_cache.shape[2]
-            k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+            k_pos = ring_positions(ring, jnp.max(tok_pos, axis=1))
         else:
-            # contiguous verify block: one vmapped T-wide update per
-            # slot. The speculative pool's max_len headroom (budget
-            # ceiling + k_max + 1) guarantees the block never reaches
-            # the cache end, so the write cannot clamp.
-            row0 = tok_pos[:, 0]
-            k_cache = write_kv_slot(cache["k"], kn, jnp.maximum(row0, 0),
-                                    row0 >= 0)
-            v_cache = write_kv_slot(cache["v"], vn, jnp.maximum(row0, 0),
-                                    row0 >= 0)
-            S = k_cache.shape[2]
+            # positional cache: the new rows go into the buffer that
+            # came in — layer ``layer`` of the stack the layer loop
+            # carries, or a cache of its own (layer None) — and nothing
+            # else of it moves. Live rows sit below max_len (the engines
+            # check prompt + budget, and the speculative pool's headroom
+            # covers a verify block); the kernel reads every position
+            # and masks k_pos > q_pos per row, so stale entries beyond
+            # each slot's position never contribute.
+            k_cache, v_cache = launch(ops.kv_write, cache["k"], cache["v"],
+                                      kn, vn, tok_pos, layer)
+            S = k_cache.shape[-2]
             k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        attend = (ops.prefill_attention if mode == "prefill_chunk"
-                  else ops.verify_attention)
-        out = launch(
-            attend, q, k_cache, v_cache, k_pos.astype(jnp.int32), tok_pos,
-            window=window,
-        )                                                      # (B, T, H, hd)
-        new_cache = {"k": k_cache, "v": v_cache}
-    else:  # decode: ragged, native-layout, one batched kernel call
-        q, k_new, v_new = project_qkv(cfg, p, x, x)
-        pos_vec = decode_pos_vector(pos, B)                    # (B,)
-        q = rope(q, pos_vec[:, None], theta, rd)
-        k_new = rope(k_new, pos_vec[:, None], theta, rd)
-        kn = jnp.swapaxes(k_new, 1, 2)                         # (B, K, 1, hd)
-        vn = jnp.swapaxes(v_new, 1, 2)
-        # inactive slots (pos < 0) write NOTHING: a mid-prefill slot's
-        # freshly-written prompt KV at position 0 must survive decode
-        # steps dispatched while its remaining chunks are still queued
-        live = pos_vec >= 0
-        if window:
-            # ring size comes from the cache (it may be over-allocated
-            # beyond the attention window for speculative rounds); the
-            # window mask itself is positional, never layout
-            ring = cache["k"].shape[2]
-            slot = jnp.maximum(pos_vec, 0) % ring
-            k_cache = write_kv_slot(cache["k"], kn, slot, live)
-            v_cache = write_kv_slot(cache["v"], vn, slot, live)
-            k_pos = ring_positions(ring, pos_vec)              # (B, ring)
+        k_pos = k_pos.astype(jnp.int32)
+        if mode == "decode":
+            out = launch(ops.decode_attention, q[:, 0], k_cache, v_cache,
+                         k_pos, tok_pos[:, 0], layer, window=window)[:, None]
         else:
-            k_cache = write_kv_slot(cache["k"], kn, jnp.maximum(pos_vec, 0),
-                                    live)
-            v_cache = write_kv_slot(cache["v"], vn, jnp.maximum(pos_vec, 0),
-                                    live)
-            S = k_cache.shape[2]
-            # the kernel masks k_pos > q_pos per slot; stale entries
-            # beyond each slot's position never contribute
-            k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        out = launch(
-            ops.decode_attention, q[:, 0], k_cache, v_cache,
-            k_pos.astype(jnp.int32), pos_vec, window=window,
-        )[:, None]                                             # (B, 1, H, hd)
+            attend = (ops.prefill_attention if mode == "prefill_chunk"
+                      else ops.verify_attention)
+            out = launch(attend, q, k_cache, v_cache, k_pos, tok_pos, layer,
+                         window=window)                        # (B, T, H, hd)
         new_cache = {"k": k_cache, "v": v_cache}
     return dense(out.reshape(B, Tq, -1), p["wo"], dtype=cfg.dtype), new_cache
 

@@ -90,8 +90,30 @@ def _attn_theta(cfg: ArchConfig, kind: str) -> float:
     return cfg.rope_theta
 
 
-def block_apply(cfg: ArchConfig, kind: str, p, x, *, mode: str, cache, pos, enc_out):
-    """Returns (x, new_cache, aux)."""
+def carries_kv(cfg: ArchConfig, kind: str) -> bool:
+    """Whether a block's cache is one positional self-attention KV
+    cache (window 0), which the layer loop of a decode, verify or
+    chunk step carries whole and updates in place. Ring caches
+    (window > 0), cross caches and recurrent states are scanned
+    over instead, a layer slice in and a layer out."""
+    return kind in ("attn", "global", "moe", "shared_attn") or (
+        kind in ("swa", "swa_moe") and not cfg.window)
+
+
+def kv_path(cfg: ArchConfig) -> str:
+    """``"in_place"`` when every cache of the model is carried through
+    its step's layer loop (no cache-sized copy in a decode, verify or
+    chunk step), else ``"copied"``."""
+    kinds = set(cfg.cycle) | set(cfg.tail)
+    if not cfg.costing and all(carries_kv(cfg, k) for k in kinds):
+        return "in_place"
+    return "copied"
+
+
+def block_apply(cfg: ArchConfig, kind: str, p, x, *, mode: str, cache, pos,
+                enc_out, layer=None):
+    """Returns (x, new_cache, aux). ``layer`` (a () int32) marks
+    ``cache`` as the whole carried stack and indexes this layer in it."""
     aux = ZERO_AUX()
     if kind in ("attn", "swa", "global", "shared_attn", "enc_attn"):
         h = apply_norm(cfg, p["norm1"], x)
@@ -119,6 +141,7 @@ def block_apply(cfg: ArchConfig, kind: str, p, x, *, mode: str, cache, pos, enc_
                     cache=cache,
                     pos=pos,
                     rope_theta=_attn_theta(cfg, kind),
+                    layer=layer,
                 )
         x = x + a_out
         h2 = apply_norm(cfg, p["norm2"], x)
@@ -135,6 +158,7 @@ def block_apply(cfg: ArchConfig, kind: str, p, x, *, mode: str, cache, pos, enc_
             window=_attn_window(cfg, kind),
             cache=cache,
             pos=pos,
+            layer=layer,
         )
         x = x + a_out
         h2 = apply_norm(cfg, p["norm2"], x)
@@ -363,49 +387,76 @@ def run_stack(
     pos=None,
     enc_out=None,
 ):
-    """Returns (x, new_caches, aux)."""
+    """Returns (x, new_caches, aux).
+
+    In a decode, verify or chunk step the positional KV caches ride the
+    layer loop's carry whole (:func:`carries_kv`): each layer writes its
+    new rows into the stacked buffer at its own index and the attention
+    kernels read the layer by index, so no layer is ever sliced out or
+    stacked back. Every other cache is scanned over, a layer slice in
+    and a layer out. The unrolled costing path scans everything."""
     cycle = cfg.cycle
     aux0 = ZERO_AUX()
     shared = params.get("shared")
+    carried_slots = set()
+    if mode in ("decode", "prefill_chunk", "verify") and not cfg.costing \
+            and caches is not None:
+        carried_slots = {f"{j}_{kind}" for j, kind in enumerate(cycle)
+                         if carries_kv(cfg, kind)}
 
     def cycle_body(carry, xs):
-        x, aux = carry
-        cyc_params, cyc_caches = xs
+        x, aux, carried = carry
+        cyc_params, cyc_caches, layer = xs
+        carried = dict(carried)
         new_caches = {}
         for j, kind in enumerate(cycle):
             slot = f"{j}_{kind}"
             p = shared if kind == "shared_attn" else cyc_params[slot]
-            c = cyc_caches[slot] if cyc_caches is not None else None
+            if slot in carried:
+                c, li = carried[slot], layer
+            else:
+                c = cyc_caches[slot] if cyc_caches is not None else None
+                li = None
             x, nc, a = block_apply(
-                cfg, kind, p, x, mode=mode, cache=c, pos=pos, enc_out=enc_out
+                cfg, kind, p, x, mode=mode, cache=c, pos=pos, enc_out=enc_out,
+                layer=li,
             )
-            if nc is not None:
+            if slot in carried:
+                carried[slot] = nc
+            elif nc is not None:
                 new_caches[slot] = nc
             aux = {k: aux[k] + a[k] for k in aux}
-        return (x, aux), new_caches if new_caches else None
+        return (x, aux, carried), new_caches if new_caches else None
 
     if cfg.n_cycles > 0:
         cyc_caches = caches["cycles"] if caches is not None else None
-        xs = (params["cycles"], cyc_caches)
+        carried0 = {k: cyc_caches[k] for k in carried_slots}
+        scanned = (None if cyc_caches is None else
+                   {k: c for k, c in cyc_caches.items()
+                    if k not in carried_slots})
+        xs = (params["cycles"], scanned,
+              jnp.arange(cfg.n_cycles, dtype=jnp.int32))
         body = cycle_body
         if mode == "full" and cfg.remat:
             body = jax.checkpoint(cycle_body, prevent_cse=False)
         if cfg.costing:
             # unrolled for cost_analysis fidelity (see ArchConfig.costing)
-            carry = (x, aux0)
+            carry = (x, aux0, carried0)
             per_cycle = []
             for r in range(cfg.n_cycles):
                 xs_r = jax.tree.map(lambda a: a[r], xs)
                 carry, y_r = body(carry, xs_r)
                 per_cycle.append(y_r)
-            (x, aux) = carry
+            (x, aux, carried) = carry
             ys = (
                 jax.tree.map(lambda *zs: jnp.stack(zs), *per_cycle)
                 if per_cycle[0] is not None
                 else None
             )
         else:
-            (x, aux), ys = lax.scan(body, (x, aux0), xs)
+            (x, aux, carried), ys = lax.scan(body, (x, aux0, carried0), xs)
+        if carried:
+            ys = {**(ys or {}), **carried}
         new_caches = {"cycles": ys, "tail": {}}
     else:
         aux = aux0

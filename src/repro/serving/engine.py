@@ -61,6 +61,7 @@ from repro.core.progressive import ProgressiveModel, ReceiverState, rebuild_para
 from repro.core.quantize import QuantizedTensor
 from repro.models.common import quantized_resident_eligible
 from repro.models.model import Model
+from repro.models.transformer import kv_path
 
 RESIDENT_MODES = ("fp", "quantized")
 
@@ -218,7 +219,11 @@ class PrecisionManagedEngine:
         self._consumed = 0  # receiver mode: stages reflected in params
         self.params = None  # live param pytree at current precision
         self._prefill = jax.jit(self._meshed(model.prefill))
-        self._decode = jax.jit(self._meshed(model.decode_step))
+        # the steps donate the cache (argument 1): the buffer that comes
+        # in is the one that goes out, with only the new rows written
+        self._decode = jax.jit(self._meshed(model.decode_step),
+                               donate_argnums=1)
+        self._kv_path = kv_path(model.cfg)
 
     def _meshed(self, fn):
         """Wrap a model entry point so its *trace* runs under
@@ -253,6 +258,15 @@ class PrecisionManagedEngine:
         if self._receiver is not None:
             return self._receiver.stages_complete
         return self.prog.n_stages
+
+    def _count_kv_step(self, mode: str) -> None:
+        """One dispatched decode, chunk or verify step, by how the
+        model's caches move through it (``models.transformer.kv_path``)."""
+        if _obs.enabled():
+            _obs.get_registry().counter(
+                "engine_kv_steps_total",
+                "dispatched steps by KV cache path").inc(
+                    mode=mode, path=self._kv_path)
 
     def decode_cache_size(self) -> int:
         """Compiled-executable count of the jitted decode step. The
@@ -387,6 +401,7 @@ class ProgressiveServer(PrecisionManagedEngine):
             logits, self.caches = self._decode(
                 self.params, self.caches, nxt, jnp.int32(self.pos)
             )
+            self._count_kv_step("decode")
             self.pos += 1
             toks.append(nxt[:, 0])
             stage_at.append(self.stage)
@@ -609,7 +624,8 @@ class SlotPoolEngine(PrecisionManagedEngine):
         # chunked admission: slot -> staged prompt + consumption offset;
         # slots here hold a request (not free) but are NOT decoding yet
         self._prefill_state: dict[int, dict] = {}
-        self._chunk_step = jax.jit(self._meshed(_make_chunk_step(model)))
+        self._chunk_step = jax.jit(self._meshed(_make_chunk_step(model)),
+                                   donate_argnums=1)
         # device-side companions updated by the chunk step when a slot's
         # prefill completes: the argmax of its last prompt row (the
         # request's first greedy token) lands in _last_tok (consumed by
@@ -680,7 +696,7 @@ class SlotPoolEngine(PrecisionManagedEngine):
             raise ValueError("PoolRequest.prompt must hold >= 1 token")
         if prompt.shape[0] + req.max_new_tokens > self.max_len:
             # write positions reach prompt_len + budget - 1; past max_len
-            # the cache write would silently clamp onto the last slot
+            # the cache write would silently drop the rows
             raise ValueError(
                 f"request needs {prompt.shape[0]} prompt + "
                 f"{req.max_new_tokens} new tokens > max_len {self.max_len}")
@@ -832,6 +848,7 @@ class SlotPoolEngine(PrecisionManagedEngine):
                 self.params, self.caches, jnp.asarray(toks), jnp.asarray(tpos),
                 jnp.asarray(frow), self.pos, self.last_logits, self._last_tok,
                 self._first_cap)
+            self._count_kv_step("prefill_chunk")
             self._tick_count += 1
             self._win_prefill_ticks += 1
             for slot in done:
@@ -890,6 +907,7 @@ class SlotPoolEngine(PrecisionManagedEngine):
                              axis=-1).astype(jnp.int32)[:, None]
             logits, self.caches = self._decode(self.params, self.caches, nxt,
                                                self.pos)
+            self._count_kv_step("decode")
             active = jnp.asarray(
                 [i in snapshot for i in range(self.n_slots)], dtype=bool)
             self.pos = jnp.where(active, self.pos + 1, self.pos)

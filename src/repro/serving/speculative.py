@@ -158,9 +158,9 @@ class _SpeculativeMixin:
                 f"zero-copy KV story)")
         self.spec = spec or SpecConfig()
         # The contiguous verify block writes T = k + 1 rows starting at
-        # the base position, and `write_kv_slot` clamps at the cache
-        # end: a cache without k_max + 1 rows of headroom past the last
-        # decodable position would silently overwrite live KV instead
+        # the base position, and the cache write drops rows past the
+        # cache end: a cache without k_max + 1 rows of headroom past the
+        # last decodable position would silently lose drafted KV instead
         # of failing. Enforce the floor at construction (mirroring
         # SlotPoolEngine.submit's prompt+budget check); start()/
         # submit() validate the per-prompt / per-request form.
@@ -169,12 +169,13 @@ class _SpeculativeMixin:
             raise ValueError(
                 f"max_len {self.max_len} < k_max + 2 = {min_len}: the "
                 f"T-wide verify write needs k_max + 1 rows of headroom "
-                f"past the base position, or it clamps onto live KV "
-                f"rows")
+                f"past the base position, or it runs off the cache "
+                f"end")
         self.controller = self.spec.make_controller()
         self.draft_params = None
         self._verify = jax.jit(self._meshed(
-            lambda p, c, t, pos: _verify_and_accept(self.model, p, c, t, pos)))
+            lambda p, c, t, pos: _verify_and_accept(self.model, p, c, t, pos)),
+            donate_argnums=1)
         self.accept_log: list[dict] = []
         if self.params is not None:
             self._refresh_params()
@@ -270,6 +271,7 @@ class _SpeculativeMixin:
         overwrites every drafted slot with target K/V."""
         if k_eff == 0:
             logits, caches = self._decode(self.params, caches, last_tok, pos)
+            self._count_kv_step("decode")
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             return g, jnp.zeros(g.shape[:1], jnp.int32), g, caches
         toks = [last_tok]
@@ -280,8 +282,10 @@ class _SpeculativeMixin:
             # the invariant says stays untouched
             pj = jnp.where(pos >= 0, pos + j, jnp.int32(-1))
             logits, caches = self._decode(self.draft_params, caches, cur, pj)
+            self._count_kv_step("decode")
             cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             toks.append(cur)
+        self._count_kv_step("verify")
         return self._verify(self.params, caches,
                             jnp.concatenate(toks, axis=1), pos)
 
@@ -380,7 +384,7 @@ class SpeculativeEngine(_SpeculativeMixin, ProgressiveServer):
             raise ValueError(
                 f"decoding {steps} steps needs max_len >= prompt + "
                 f"steps + k_max - 1 = {need}, got {self.max_len} (the "
-                f"final rounds' verify blocks would clamp at the cache "
+                f"final rounds' verify blocks would run off the cache "
                 f"end)")
         self._decoded = True
         B = int(self._first_tok.shape[0])
@@ -510,9 +514,8 @@ class SpeculativeSlotPool(_SpeculativeMixin, SlotPoolEngine):
         if prompt.shape[0] + req.max_new_tokens + self.spec.k_max \
                 > self.max_len:
             # the last round at pos = prompt + budget - 1 verify-writes
-            # k_max more rows; past max_len the write would clamp onto
-            # live KV (and a shrunken k would compile a second verify
-            # shape)
+            # k_max more rows; past max_len the write would drop them
+            # (and a shrunken k would compile a second verify shape)
             raise ValueError(
                 f"request needs {prompt.shape[0]} prompt + "
                 f"{req.max_new_tokens} new tokens + {self.spec.k_max} "

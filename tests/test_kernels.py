@@ -425,3 +425,116 @@ def test_verify_attention_dispatch_matches_kernel():
     want = flash_verify(q, k, v, k_pos, q_pos, bs=32, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# in-place KV row write and layer-indexed attention over a stacked cache
+# ---------------------------------------------------------------------------
+
+def _old_row_writes(cache, new, rows):
+    """The per-row masked ``write_kv_slot`` sequence a step used before
+    the in-place write: row t of slot b lands at rows[b, t], a negative
+    row writes nothing."""
+    from repro.models.attention import write_kv_slot
+
+    for t in range(rows.shape[1]):
+        cache = write_kv_slot(cache, new[:, :, t:t + 1],
+                              jnp.maximum(rows[:, t], 0), rows[:, t] >= 0)
+    return cache
+
+
+KV_WRITE_ROWS = {
+    # T = 1 decode: live slots, a free slot, the last position
+    "decode": [[5], [-1], [63], [16]],
+    # T = 8 chunk: a full chunk, a short last chunk, a masked slot, and
+    # a chunk whose last row is max_len - 1
+    "chunk": [list(range(8, 16)), [21, 22, 23, -1, -1, -1, -1, -1],
+              [-1] * 8, list(range(56, 64))],
+    # T = k + 1 = 5 verify block: blocks straddling a 16-row tile, and a
+    # masked slot
+    "verify": [[13, 14, 15, 16, 17], [-1] * 5, [59, 60, 61, 62, 63],
+               [0, 1, 2, 3, 4]],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(KV_WRITE_ROWS))
+def test_kv_write_matches_row_writes_byte_for_byte(case, dtype):
+    """The Pallas row write (interpret mode) and its oracle write into
+    layer 1 of a 3-layer stack exactly the bytes the old per-row masked
+    ``write_kv_slot`` writes into that layer alone, and leave every
+    other layer, slot and row untouched."""
+    from repro.kernels.kv_write import kv_write
+
+    rows = jnp.asarray(KV_WRITE_ROWS[case], jnp.int32)
+    (B, T), L, Kh, S, hd, layer = rows.shape, 3, 2, 64, 32, 1
+    ks = jax.random.split(jax.random.PRNGKey(T), 4)
+    k, v = (jax.random.normal(kk, (L, B, Kh, S, hd)).astype(dtype)
+            for kk in ks[:2])
+    kn, vn = (jax.random.normal(kk, (B, Kh, T, hd)).astype(dtype)
+              for kk in ks[2:])
+    want_k = np.array(k).copy()
+    want_v = np.array(v).copy()
+    want_k[layer] = np.array(_old_row_writes(k[layer], kn, rows))
+    want_v[layer] = np.array(_old_row_writes(v[layer], vn, rows))
+    got = {
+        "kernel": kv_write(k, v, kn, vn, rows, jnp.int32(layer),
+                           interpret=True),
+        "oracle": ref.kv_write_ref(k, v, kn, vn, rows, jnp.int32(layer)),
+    }
+    for name, (gk, gv) in got.items():
+        for g, w in ((gk, want_k), (gv, want_v)):
+            assert g.dtype == w.dtype, name
+            np.testing.assert_array_equal(
+                np.asarray(g).view(np.uint8), w.view(np.uint8),
+                err_msg=f"{name} {case}")
+
+
+def test_kv_write_entry_point_on_one_cache():
+    """``ops.kv_write`` with no layer index writes a plain (B, Kh, S, hd)
+    cache (a tail block's) like the stacked path writes one layer."""
+    from repro.kernels import ops
+
+    rows = jnp.asarray(KV_WRITE_ROWS["chunk"], jnp.int32)
+    B, T = rows.shape
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    k, v = (jax.random.normal(kk, (B, 2, 64, 32)) for kk in ks[:2])
+    kn, vn = (jax.random.normal(kk, (B, 2, T, 32)) for kk in ks[2:])
+    gk, gv = ops.kv_write(k, v, kn, vn, rows)
+    np.testing.assert_array_equal(np.asarray(gk),
+                                  np.asarray(_old_row_writes(k, kn, rows)))
+    np.testing.assert_array_equal(np.asarray(gv),
+                                  np.asarray(_old_row_writes(v, vn, rows)))
+
+
+@pytest.mark.parametrize("S", [128, 97])  # aligned; prime (tail padding)
+@pytest.mark.parametrize("rows", [1, 4])  # flash_decode, flash_verify
+def test_layer_indexed_attention_matches_sliced_layer(rows, S):
+    """The kernels and their oracles reading layer l of a stacked
+    (L, B, Kh, S, hd) cache by index give exactly what they give on that
+    layer sliced out beforehand."""
+    from repro.kernels import ops
+
+    L, B, H, Kh, hd, layer = 3, 2, 8, 2, 32, 2
+    ks = jax.random.split(jax.random.PRNGKey(S + rows), 3)
+    k = jax.random.normal(ks[0], (L, B, Kh, S, hd))
+    v = jax.random.normal(ks[1], (L, B, Kh, S, hd))
+    k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    base = jnp.array([S - rows - 1, S // 3], jnp.int32)
+    if rows == 1:
+        q = jax.random.normal(ks[2], (B, H, hd))
+        q_pos = base
+        kernel, entries = flash_decode, (ops.decode_attention,)
+    else:
+        q = jax.random.normal(ks[2], (B, rows, H, hd))
+        q_pos = base[:, None] + jnp.arange(rows, dtype=jnp.int32)
+        kernel = flash_verify
+        entries = (ops.verify_attention, ops.prefill_attention)
+    li = jnp.int32(layer)
+    got = kernel(q, k, v, k_pos, q_pos, li, bs=32, interpret=True)
+    want = kernel(q, k[layer], v[layer], k_pos, q_pos, bs=32, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for entry in entries:
+        np.testing.assert_array_equal(
+            np.asarray(entry(q, k, v, k_pos, q_pos, li)),
+            np.asarray(entry(q, k[layer], v[layer], k_pos, q_pos)))

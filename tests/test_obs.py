@@ -449,3 +449,51 @@ def test_report_cli_check_prom(tmp_path, capsys):
 def test_report_cli_requires_input(capsys):
     with pytest.raises(SystemExit):
         report_mod.main([])
+
+
+# ---------------------------------------------------------------------------
+# engine_kv_steps_total: each dispatched step, by how the caches move
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch,over,path", [
+    ("olmo-1b", {}, "in_place"),                     # positional caches
+    ("mixtral-8x22b", {"n_experts": 2, "top_k": 1,
+                       "window": 8}, "copied"),      # ring caches
+])
+def test_engine_kv_steps_total_counts_cache_path(arch, over, path):
+    """A pool counts every dispatched decode and chunk step once, under
+    the one path its model's caches take: ``in_place`` when the layer
+    loop carries every cache, ``copied`` when a ring is scanned over."""
+    from repro.serving.engine import PoolRequest, SlotPoolEngine
+
+    cfg = get_config(arch).reduced(n_layers=2, d_model=32, d_ff=64,
+                                   vocab=64, n_heads=2, n_kv=2, **over)
+    model = build_model(cfg)
+    prog = divide(model.init(jax.random.PRNGKey(0)))
+    with obs.telemetry(True) as reg:
+        pool = SlotPoolEngine(model, prog, n_slots=2, max_len=16,
+                              chunked_prefill=True, prefill_chunk=4,
+                              prefill_buckets=False)
+        pool.receive_stage()
+        for rid, n in enumerate((6, 3, 5)):
+            pool.submit(PoolRequest(
+                rid=rid, prompt=jnp.arange(n, dtype=jnp.int32) % cfg.vocab,
+                max_new_tokens=3))
+        pool.run()
+        c = reg.get("engine_kv_steps_total")
+        assert pool._step_count > 0 and pool._tick_count > 0
+        assert dict(c.samples()) == {
+            (("mode", "decode"), ("path", path)): pool._step_count,
+            (("mode", "prefill_chunk"), ("path", path)): pool._tick_count,
+        }
+
+
+def test_benchmarked_configurations_count_in_place():
+    """Every cache of the benchmark's configurations is a positional
+    KV cache, so all their steps count ``path="in_place"``."""
+    from repro.models.transformer import kv_path
+
+    for name in ("olmo-1b", "minitron-4b"):
+        assert kv_path(get_config(name)) == "in_place", name
+    assert kv_path(get_config("olmo-1b").for_costing()) == "copied"
+    assert kv_path(get_config("gemma3-27b")) == "copied"  # local rings

@@ -7,7 +7,9 @@ runs, so no result or time is checked here; ``chip_smoke.py`` does that
 on the chip. The topology is described inside a fixture, never at
 import: only one process may load the TPU library, and the others skip.
 """
+import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -16,9 +18,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.core.quantize import QuantizedTensor
 from repro.kernels import bitplane, decode_attention, dequant_matmul, \
-    verify_attention
+    kv_write, ops, verify_attention
+from repro.models.common import quantized_resident_eligible
 from repro.models.model import build_model
+from repro.serving.engine import _make_chunk_step
 
 OLMO = get_config("olmo-1b")
 MINITRON = get_config("minitron-4b")
@@ -177,3 +182,103 @@ def test_plane_unpack_compiles_at_whole_olmo_buffer(
                                         interpret=False),
         shape_on_chip((n * 2 // 8,), jnp.uint8))
     assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# the KV cache through a whole step: written in place, never copied
+# ---------------------------------------------------------------------------
+
+# (configuration, slots, max_len) of each benchmark cell's pool
+STEP_SHAPES = {
+    "minitron4b": (dataclasses.replace(MINITRON, n_layers=4), SLOTS, S),
+    "olmo1b": (OLMO, 8, 1280),
+}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\{[^}]*\} "
+                    r"([\w-]+)\(")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice", "scatter")
+
+
+def _resident_params(model, shape_on_chip):
+    """Shapes of the quantized-resident parameters the benchmark's pool
+    serves: every eligible leaf a 16-bit code view with its affine."""
+    def leaf(path, x):
+        if not quantized_resident_eligible(path):
+            return shape_on_chip(x.shape, x.dtype)
+        aff = lambda dt=jnp.float32: shape_on_chip(x.shape[:-2] + (1, 1), dt)  # noqa: E731
+        return QuantizedTensor(q=shape_on_chip(x.shape, jnp.uint16),
+                               lo=aff(), hi=aff(), bits=16,
+                               orig_dtype=x.dtype, scale=aff(),
+                               offset=aff(), received_bits=aff(jnp.int32))
+    return jax.tree_util.tree_map_with_path(
+        leaf, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+
+def _cache_moves(hlo: str, dtype, layer_elems: int) -> list[str]:
+    """Instructions of the cache's dtype, one layer's cache or larger,
+    that copy, slice or update it (fused ones by their fusion's name).
+    The weight codes (uint) and logits (f32) are another matter."""
+    dt = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    out = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or m.group(2) != dt:
+            continue
+        name, _, dims, op = m.groups()
+        n = int(np.prod([int(d) for d in dims.split(",") if d]))
+        if n >= layer_elems and (op in _MOVES or (
+                op == "fusion" and any(mv in name for mv in _MOVES))):
+            out.append(f"{op} {name} {dt}[{dims}]")
+    return out
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", sorted(STEP_SHAPES))
+def test_step_updates_kv_cache_in_place(shape_on_chip, no_persistent_cache,
+                                        monkeypatch, cell, step):
+    """A decode step and the pool's chunk step, compiled with the Pallas
+    kernels at a benchmark cell's shapes with the cache donated as the
+    engine donates it: no layer of the cache is copied, sliced out or
+    rebuilt, and the cache parameters alias the outputs."""
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg, B, max_len = STEP_SHAPES[cell]
+    model = build_model(cfg)
+    params = _resident_params(model, shape_on_chip)
+    caches = jax.tree.map(lambda a: shape_on_chip(a.shape, a.dtype),
+                          jax.eval_shape(lambda: model.init_caches(B, max_len)))
+    i32 = lambda *shape: shape_on_chip(shape, jnp.int32)  # noqa: E731
+    if step == "decode":
+        fn, rest = model.decode_step, (i32(B, 1), i32(B))
+    else:
+        fn = _make_chunk_step(model)
+        rest = (i32(B, CHUNK), i32(B, CHUNK), i32(B), i32(B),
+                shape_on_chip((B, cfg.vocab), jnp.float32), i32(B, 1), i32(B))
+    compiled = jax.jit(fn, donate_argnums=1).lower(params, caches, *rest)
+    hlo = compiled.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    layer_elems = B * cfg.n_kv * max_len * cfg.hd
+    assert not _cache_moves(hlo, cfg.dtype, layer_elems)
+    # the entry parameters keep their argument paths as names
+    cache_args = {int(n) for n in re.findall(
+        r"%caches\S* = \S+ parameter\((\d+)\)", hlo)}
+    assert len(cache_args) == len(jax.tree.leaves(caches))
+    header = hlo.split("\n", 1)[0]
+    aliased = {int(a) for a in re.findall(r"\((\d+), \{\}, (?:may|must)-alias",
+                                          header)}
+    assert cache_args <= aliased, header[:400]
+
+
+@pytest.mark.parametrize("rows", [1, CHUNK])  # decode, prefill chunk
+def test_kv_write_compiles_at_minitron(shape_on_chip, no_persistent_cache,
+                                       rows):
+    """The row write over minitron4b.offline's 4-layer stack of 64 slots
+    x 2048, with the stack aliased to the output."""
+    kv = shape_on_chip((4, SLOTS, MINITRON.n_kv, S, MINITRON.hd), jnp.bfloat16)
+    new = shape_on_chip((SLOTS, MINITRON.n_kv, rows, MINITRON.hd),
+                        jnp.bfloat16)
+    hlo = jax.jit(lambda k, v, kn, vn, r, li: kv_write.kv_write(
+        k, v, kn, vn, r, li), donate_argnums=(0, 1)).lower(
+            kv, kv, new, new, shape_on_chip((SLOTS, rows), jnp.int32),
+            shape_on_chip((), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "input_output_alias={ {0}: (0, {}, may-alias), " \
+           "{1}: (1, {}, may-alias) }" in hlo
